@@ -66,7 +66,7 @@ def main() -> int:
     args = ap.parse_args()
 
     # 2 host devices BEFORE the backend initializes: the pod axis needs rank 2
-    from repro.launch.mesh import ensure_host_devices
+    from repro.launch.mesh import ensure_host_devices, make_mesh
 
     ensure_host_devices(2)
 
@@ -95,7 +95,7 @@ def main() -> int:
                      batch_size=4, seq_len=16, log_every=2)
     ml = MultiLevelConfig(n_levels=2, alpha=0.25, e_a_frac=0.25,
                           e_small_frac=0.5)
-    mesh = jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((2, 1, 1), ("pod", "data", "model"))
     bf = batch_fn_for(cfg, tc)
 
     runs: Dict[str, Dict] = {}

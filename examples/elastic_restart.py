@@ -15,7 +15,11 @@
 For the real CLI versions: `--mesh DxM` + SIGKILL/SIGTERM drills live in
 scripts/smoke_resume.sh and tests/test_system.py / test_multiprocess.py.
 
-    PYTHONPATH=src python examples/elastic_restart.py
+A CPU drill: act 3 starts two trainers while this process holds its own
+backend, and a chip belongs to one process at a time, so every child runs
+with ``JAX_PLATFORMS=cpu`` (gloo collectives between the two processes).
+
+    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/elastic_restart.py
 """
 import os
 import shutil
@@ -34,6 +38,7 @@ from repro.checkpoint import CheckpointManager
 from repro.config import MultiLevelConfig, TrainConfig
 from repro.configs import get_config
 from repro.core.vcycle import VCycleRunner
+from repro.launch.mesh import make_mesh
 from repro.launch.train import make_batch_fn, make_vcycle_save_cb, train_vcycle_ckpt
 from repro.models.api import build_model, init_train_state, make_train_step
 
@@ -68,7 +73,7 @@ def main():
     print("== phase 2: resume onto a different mesh layout ==")
     # container has 1 CPU device; the mechanism is identical for any topology:
     # pass target NamedShardings and restore() re-shards with device_put.
-    mesh_b = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_b = make_mesh((1, 1), ("data", "model"))
     p0, o0 = init_train_state(model, tc, jax.random.PRNGKey(0))
     sh = {
         "params": jax.tree.map(lambda _: NamedSharding(mesh_b, P()), p0),
@@ -112,7 +117,7 @@ def main_vcycle():
     # params_before_* trees all land on the mesh layouts (the container has 1
     # CPU device, so 1x1; the mechanism is identical for any DxM -- the
     # launcher's `--mesh 2x1` does exactly this after a `--mesh 1x2` save)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     out = train_vcycle_ckpt(cfg, ml, tc, ckpt=cm, ckpt_every=4, mesh=mesh)
     print(f"finished: final loss {out.history.loss[-1]:.4f}, "
           f"total FLOPs {out.total_flops:.3e}")
@@ -132,7 +137,7 @@ def main_multiprocess():
             "--ckpt-dir", CKPT_MP, "--ckpt-every", "1000"]
     mp = ["--mesh", "2x1", "--coordinator", f"127.0.0.1:{port}",
           "--num-processes", "2"]
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     logs = [f"{CKPT_MP}.rank{i}.log" for i in (0, 1)]
     os.makedirs(CKPT_MP, exist_ok=True)
     procs = []

@@ -1,5 +1,7 @@
 #!/usr/bin/env bash
-# End-to-end preemption drills for the launcher, three acts:
+# End-to-end preemption drills for the launcher, four acts.  A CPU drill:
+# act 3 runs two trainers at once and a chip belongs to one process at a
+# time, so every trainer here runs with JAX_PLATFORMS=cpu.
 #
 # Act 1 -- SIGKILL (no notice):
 #   1. start a real `python -m repro.launch.train --vcycle` run,
@@ -46,6 +48,7 @@ CKPT4=$(mktemp -d)
 LOG4=$(mktemp)
 trap 'rm -rf "$CKPT" "$LOG" "$CKPT2" "$LOG2" "$CKPT3" "$LOG3A" "$LOG3B" "$CKPT4" "$LOG4"' EXIT
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
+export JAX_PLATFORMS=cpu
 
 ARGS=(--arch tinyllama-1.1b --smoke --vcycle --levels 2 --steps 40
       --batch 2 --seq 16 --ckpt-dir "$CKPT" --ckpt-every 3)

@@ -1,5 +1,7 @@
 #!/usr/bin/env bash
-# Train->serve soak drill through the real CLIs:
+# Train->serve soak drill through the real CLIs (a CPU drill: the trainer
+# and the server run at the same time, and a chip belongs to one process at
+# a time, so both run with JAX_PLATFORMS=cpu):
 #
 #   1. start a `python -m repro.launch.train --vcycle` run publishing a
 #      checkpoint every 2 global steps,
@@ -31,6 +33,7 @@ cleanup() {
 }
 trap cleanup EXIT
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
+export JAX_PLATFORMS=cpu
 
 python -m repro.launch.train --arch tinyllama-1.1b --smoke --vcycle \
   --levels 2 --steps 40 --batch 2 --seq 16 \

@@ -85,10 +85,10 @@ def _stack_decoalesce(w: jax.Array, dim: int, w0: float) -> jax.Array:
     weight (T_out rows are 1.0, T_in rows 0.5).
 
     Duplication is broadcast+reshape, NOT ``concatenate([w, w])``: XLA's SPMD
-    partitioner miscompiles a concat whose operands alias the same *sharded*
-    tensor (the halves get summed -- jaxlib 0.4.37 CPU/GSPMD), and the
-    aliasing survives a ``w + 0.0`` copy via CSE.  Broadcast lowers cleanly
-    under any sharding and is the same single HBM pass."""
+    partitioner has miscompiled a concat whose operands alias the same
+    *sharded* tensor (the halves got summed), and the aliasing survives a
+    ``w + 0.0`` copy via CSE.  Broadcast lowers cleanly under any sharding
+    and is the same single HBM pass."""
     lead = jnp.moveaxis(w, dim, 0)
     dup = jnp.broadcast_to(lead[None], (2,) + lead.shape)
     dup = dup.reshape((2 * lead.shape[0],) + lead.shape[1:])

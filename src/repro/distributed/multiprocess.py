@@ -45,7 +45,7 @@ def is_primary() -> bool:
 
 
 def _coordination_client():
-    try:  # private but stable across the 0.4.x line; None when not distributed
+    try:  # private jax API; None when not distributed
         from jax._src import distributed as _dist
 
         return _dist.global_state.client

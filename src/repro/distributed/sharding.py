@@ -156,21 +156,25 @@ def clear_mesh_ctx() -> None:
 
 @contextlib.contextmanager
 def mesh_ctx(mesh: Mesh, rules: Optional[Dict[str, AxisMap]] = None):
-    """Enter mesh: layer-level ``shard_l`` constraints become active."""
+    """Enter mesh: layer-level ``shard_l`` constraints become active, and
+    Pallas kernels run per shard (``kernels/dispatch.py``).  No jax-level
+    mesh is set: every constraint names its mesh in a ``NamedSharding``, and
+    arrays created inside (batches, projection matrices) stay uncommitted,
+    so jit places them by its ``in_shardings``."""
     prev = (_CTX["mesh"], _CTX["rules"])
     set_mesh_ctx(mesh, rules)
-    # jax >= 0.5 scopes the mesh with use_mesh; on older jax the Mesh object
-    # itself is the context manager that binds its axis names
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
     try:
-        with (use_mesh(mesh) if use_mesh is not None else mesh):
-            yield mesh
+        yield mesh
     finally:
         _CTX["mesh"], _CTX["rules"] = prev
 
 
 def current_mesh() -> Optional[Mesh]:
     return _CTX["mesh"]
+
+
+def current_rules() -> Dict[str, AxisMap]:
+    return _CTX["rules"] or RULES
 
 
 @contextlib.contextmanager

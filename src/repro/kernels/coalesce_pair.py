@@ -20,21 +20,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import LANE, sublane, tile
+
 
 def _pair_kernel(a_ref, b_ref, o_ref, *, w0: float):
     o_ref[...] = (w0 * (a_ref[...].astype(jnp.float32)
                         + b_ref[...].astype(jnp.float32))).astype(o_ref.dtype)
 
 
-def divisor_block(n: int, pref: int) -> int:
-    """Largest divisor of n that is <= pref (keeps tiles HW-aligned when the
-    dim allows, and always valid).  On odd/prime dims this collapses to 1 --
-    per-element grid programs; the dispatch layer detects that degenerate case
-    and falls back to the XLA backend instead of calling this kernel."""
-    b = min(pref, n)
-    while n % b:
-        b -= 1
-    return b
+def _pad_halves(w: jax.Array, axis: int, half: int, half_p: int) -> jax.Array:
+    """Pad each half of ``axis`` from ``half`` to ``half_p`` so pair (i, i +
+    half) stays at block offset ``half_p // block``."""
+    a = jax.lax.slice_in_dim(w, 0, half, axis=axis)
+    b = jax.lax.slice_in_dim(w, half, 2 * half, axis=axis)
+    pad = [(0, 0), (0, 0)]
+    pad[axis] = (0, half_p - half)
+    return jnp.concatenate([jnp.pad(a, pad), jnp.pad(b, pad)], axis=axis)
 
 
 def coalesce_pair(
@@ -42,37 +43,49 @@ def coalesce_pair(
     *,
     axis: int,
     w0: float = 0.5,
-    block: int = 256,
+    block: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Merge index pairs (i, i + n/2) along ``axis`` with weight ``w0``."""
+    """Merge index pairs (i, i + n/2) along ``axis`` with weight ``w0``.
+
+    Blocks are at most ``block`` per dim and Mosaic-legal (``tiling.tile``):
+    a dim with no aligned divisor is padded -- the paired dim half by half,
+    the other dim at its end -- and the output sliced back."""
     if w.ndim != 2:
         raise ValueError("coalesce_pair expects a 2D weight (fold other dims first)")
     n = w.shape[axis]
     if n % 2:
         raise ValueError(f"axis {axis} size {n} must be even")
     half = n // 2
-    r, c = w.shape
+    other = w.shape[1 - axis]
+    align = (sublane(w.dtype), LANE)
+    bp, half_p = tile(half, block, align[axis], whole=False)
+    bo, other_p = tile(other, block, align[1 - axis])
+    if half_p != half:
+        w = _pad_halves(w, axis, half, half_p)
+    if other_p != other:
+        pad = [(0, 0), (0, 0)]
+        pad[1 - axis] = (0, other_p - other)
+        w = jnp.pad(w, pad)
+    off = half_p // bp
     if axis == 0:
-        br = divisor_block(half, block)
-        bc = divisor_block(c, block)
-        grid = (half // br, c // bc)
-        a_spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
-        b_spec = pl.BlockSpec((br, bc), lambda i, j: (i + half // br, j))
-        out_shape = jax.ShapeDtypeStruct((half, c), w.dtype)
+        blk = (bp, bo)
+        grid = (half_p // bp, other_p // bo)
+        b_map = lambda i, j: (i + off, j)
+        out_shape = (half_p, other_p)
     else:
-        br = divisor_block(r, block)
-        bc = divisor_block(half, block)
-        grid = (r // br, half // bc)
-        a_spec = pl.BlockSpec((br, bc), lambda i, j: (i, j))
-        b_spec = pl.BlockSpec((br, bc), lambda i, j: (i, j + half // bc))
-        out_shape = jax.ShapeDtypeStruct((r, half), w.dtype)
-
-    return pl.pallas_call(
+        blk = (bo, bp)
+        grid = (other_p // bo, half_p // bp)
+        b_map = lambda i, j: (i, j + off)
+        out_shape = (other_p, half_p)
+    out = pl.pallas_call(
         functools.partial(_pair_kernel, w0=w0),
         grid=grid,
-        in_specs=[a_spec, b_spec],
-        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-        out_shape=out_shape,
+        in_specs=[pl.BlockSpec(blk, lambda i, j: (i, j)), pl.BlockSpec(blk, b_map)],
+        out_specs=pl.BlockSpec(blk, lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(out_shape, w.dtype),
         interpret=interpret,
     )(w, w)
+    if axis == 0:
+        return out[:half, :other]
+    return out[:other, :half]

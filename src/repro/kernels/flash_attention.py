@@ -8,6 +8,12 @@ path only gets from the pairs-scan).
 
 Grid: (batch*heads, n_q_blocks, n_k_blocks); the k-block axis is innermost so
 scratch accumulators persist per (bh, qi) like the reference TPU kernel.
+Per-row statistics (running max, denominator, log-sum-exp, the backward's
+delta) are kept replicated across ``STAT_LANES`` lanes, so every block's last
+two dims are Mosaic-legal: ``(bq, 128)`` rather than a 1-D ``(bq,)`` row.
+Masked scores are the finite ``NEG_INF``: the first k-block of every row holds
+key 0, which causality always admits, so the running max is finite from the
+first computed tile and masked probabilities come out exactly 0.
 
 The backward follows the flash-attention recipe (same as the XLA-level
 ``_flash_xla_bwd`` in layers/attention.py): save only (q, k, v, out, lse),
@@ -22,6 +28,7 @@ Validated in interpret mode against ref.naive_attention, values and grads
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -29,7 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import round_up, sublane, tile
+
 NEG_INF = -1e30
+STAT_LANES = 128  # per-row statistics, replicated across one lane tile
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -50,17 +60,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, -jnp.inf)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
+            s = jnp.where(_causal_mask(qi, ki, bq, bk), s, NEG_INF)
+        m_prev = m_scr[...]  # [bq, STAT_LANES], lanes equal
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new[:, :1])
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1)
-        acc_scr[...] = corr[:, None] * acc_scr[...] + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = corr[:, :1] * acc_scr[...] + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
     if causal:
@@ -72,13 +79,24 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _out():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
         lse_ref[0] = m_scr[...] + jnp.log(l)
+
+
+def _causal_mask(qi, ki, bq: int, bk: int):
+    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return kpos <= qpos
+
+
+def _stat_lanes(x: jax.Array) -> jax.Array:
+    """[BH, S] row statistic -> [BH, S, STAT_LANES] kernel operand."""
+    return jnp.broadcast_to(x[..., None], x.shape + (STAT_LANES,))
 
 
 def _fwd_call(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
               interpret: bool) -> Tuple[jax.Array, jax.Array]:
-    """Flattened [B*H, S, D] forward; returns (out, lse)."""
+    """Flattened [B*H, S, D] forward; returns (out, lse [B*H, S])."""
     BH, S, D = q.shape
     T = k.shape[1]
     Dv = v.shape[2]
@@ -95,28 +113,52 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq, STAT_LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, Dv), q.dtype),
-            jax.ShapeDtypeStruct((BH, S), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S, STAT_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, STAT_LANES), jnp.float32),
+            pltpu.VMEM((bq, STAT_LANES), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse[..., 0]
 
 
-def _resolve_blocks(S: int, T: int, block_q: int, block_k: int) -> Tuple[int, int]:
-    bq = min(block_q, S)
-    bk = min(block_k, T)
-    if S % bq or T % bk:
-        raise ValueError(f"S={S} T={T} must divide block sizes ({bq},{bk})")
-    return bq, bk
+def flash_tiles(S: int, T: int, block_q: int, block_k: int, causal: bool,
+                dtype) -> Optional[Tuple[int, int, int, int]]:
+    """``(bq, bk, S_padded, T_padded)`` for Mosaic-legal q/k blocks, or None
+    when the kernel cannot express the shape: padded keys need a mask, which
+    only causal attention with ``S == T`` supplies (a padded key sits past
+    every real query)."""
+    bq, Sp = tile(S, block_q, sublane(dtype))
+    bk, Tp = tile(T, block_k, sublane(dtype))
+    if Tp != T and not (causal and S == T):
+        return None
+    if causal and S == T and Sp != Tp:
+        # one padded length for both, so the diagonal stays aligned
+        Sp = Tp = round_up(S, bq * bk // math.gcd(bq, bk))
+    return bq, bk, Sp, Tp
+
+
+def _resolve_blocks(S: int, T: int, block_q: int, block_k: int, causal: bool,
+                    dtype) -> Tuple[int, int, int, int]:
+    tiles = flash_tiles(S, T, block_q, block_k, causal, dtype)
+    if tiles is None:
+        raise ValueError(f"S={S} T={T} (causal={causal}) needs padded keys "
+                         f"the kernel cannot mask; blocks ({block_q},{block_k})")
+    return tiles
+
+
+def _pad_seq(x: jax.Array, n: int) -> jax.Array:
+    """Zero-pad the sequence dim (2) of [B, H, S, D] to ``n``."""
+    if x.shape[2] == n:
+        return x
+    return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
 
 
 def flash_attention(
@@ -135,11 +177,12 @@ def flash_attention(
     T = k.shape[2]
     Dv = v.shape[3]
     scale = D ** -0.5 if scale is None else scale
-    bq, bk = _resolve_blocks(S, T, block_q, block_k)
-    out, _ = _fwd_call(q.reshape(B * H, S, D), k.reshape(B * H, T, D),
-                       v.reshape(B * H, T, Dv), causal=causal, scale=scale,
-                       bq=bq, bk=bk, interpret=interpret)
-    return out.reshape(B, H, S, Dv)
+    bq, bk, Sp, Tp = _resolve_blocks(S, T, block_q, block_k, causal, q.dtype)
+    out, _ = _fwd_call(_pad_seq(q, Sp).reshape(B * H, Sp, D),
+                       _pad_seq(k, Tp).reshape(B * H, Tp, D),
+                       _pad_seq(v, Tp).reshape(B * H, Tp, Dv), causal=causal,
+                       scale=scale, bq=bq, bk=bk, interpret=interpret)
+    return out.reshape(B, H, Sp, Dv)[:, :, :S]
 
 
 # ---------------------------------------------------------------------------
@@ -156,23 +199,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
         k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0].astype(jnp.float32)  # [bk, Dv]
-        do = do_ref[0].astype(jnp.float32)  # [bq, Dv]
-        lse = lse_ref[0]  # [bq]
-        delta = delta_ref[0]  # [bq]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, -jnp.inf)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [bq, bk]
-        ds = p * (dp - delta[:, None]) * scale
+        ds = _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+                     scale=scale, causal=causal, bq=bq, bk=bk)[1]
         dq_scr[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
     if causal:
@@ -183,6 +212,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(ki == nk - 1)
     def _out():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
+            scale: float, causal: bool, bq: int, bk: int):
+    """Recomputed probabilities p and score gradient ds of one [bq, bk] tile."""
+    q = q_ref[0].astype(jnp.float32)  # [bq, D]
+    v = v_ref[0].astype(jnp.float32)  # [bk, Dv]
+    do = do_ref[0].astype(jnp.float32)  # [bq, Dv]
+    lse = lse_ref[0][:, :1]  # [bq, 1]
+    delta = delta_ref[0][:, :1]  # [bq, 1]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        s = jnp.where(_causal_mask(qi, ki, bq, bk), s, NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # [bq, bk]
+    return p, p * (dp - delta) * scale
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
@@ -197,25 +244,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
         k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0].astype(jnp.float32)  # [bk, Dv]
-        do = do_ref[0].astype(jnp.float32)  # [bq, Dv]
-        lse = lse_ref[0]  # [bq]
-        delta = delta_ref[0]  # [bq]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, -jnp.inf)
-        p = jnp.exp(s - lse[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)  # [bq, bk]
+        p, ds = _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+                        scale=scale, causal=causal, bq=bq, bk=bk)
+        q = q_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
         dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [bq, bk]
-        ds = p * (dp - delta[:, None]) * scale
         dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                            preferred_element_type=jnp.float32)
 
@@ -238,13 +273,15 @@ def _bwd_call(q, k, v, out, lse, do, *, causal: bool, scale: float, bq: int,
     Dv = v.shape[2]
     nq, nk = S // bq, T // bk
     # rowwise correction term D_i = sum_v do*out (cheap elementwise pass)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = _stat_lanes(jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                                axis=-1))
+    lse = _stat_lanes(lse)
 
     q_spec_i = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     k_spec_j = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0))
     v_spec_j = pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0))
     do_spec_i = pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))
-    row_spec_i = pl.BlockSpec((1, bq), lambda b, i, j: (b, i))
+    row_spec_i = pl.BlockSpec((1, bq, STAT_LANES), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -262,7 +299,7 @@ def _bwd_call(q, k, v, out, lse, do, *, causal: bool, scale: float, bq: int,
     k_spec_i = pl.BlockSpec((1, bk, D), lambda b, i, j: (b, i, 0))
     v_spec_i = pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, i, 0))
     do_spec_j = pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, j, 0))
-    row_spec_j = pl.BlockSpec((1, bq), lambda b, i, j: (b, j))
+    row_spec_j = pl.BlockSpec((1, bq, STAT_LANES), lambda b, i, j: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq),
@@ -342,6 +379,7 @@ def flash_attention_with_vjp(
     B, H, S, D = q.shape
     T = k.shape[2]
     scale = D ** -0.5 if scale is None else scale
-    bq, bk = _resolve_blocks(S, T, block_q, block_k)
-    return _flash_vjp(q, k, v, bool(causal), float(scale), bq, bk,
-                      bool(interpret))
+    bq, bk, Sp, Tp = _resolve_blocks(S, T, block_q, block_k, causal, q.dtype)
+    out = _flash_vjp(_pad_seq(q, Sp), _pad_seq(k, Tp), _pad_seq(v, Tp),
+                     bool(causal), float(scale), bq, bk, bool(interpret))
+    return out[:, :, :S]

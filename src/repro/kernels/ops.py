@@ -48,7 +48,7 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths, *,
 
 
 @functools.partial(jax.jit, static_argnames=("axis", "w0", "block", "interpret"))
-def coalesce_pair(w, *, axis, w0=0.5, block=256, interpret=None):
+def coalesce_pair(w, *, axis, w0=0.5, block=512, interpret=None):
     interp = (not _on_tpu()) if interpret is None else interpret
     return _coalesce_pair(w, axis=axis, w0=w0, block=block, interpret=interp)
 

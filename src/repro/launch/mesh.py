@@ -29,9 +29,10 @@ launch/README.md, "Mesh-sharded paged decode").
 from __future__ import annotations
 
 import os
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def parse_mesh_arg(spec: str) -> Tuple[int, ...]:
@@ -116,13 +117,25 @@ def init_distributed(coordinator: str, num_processes: int, process_id: int,
         return
     if local_devices and local_devices > 1:
         _force_host_device_flag(local_devices)
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # jax build without gloo / renamed
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """The one mesh constructor of the repo: every axis is ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which a gather or a
+    ``with_sharding_constraint`` must spell out its output sharding.  The
+    model code leaves propagation to GSPMD (``shard_l`` constraints, jit
+    ``in_shardings``/``out_shardings``), which needs ``Auto`` axes.
+    ``devices`` defaults to ``jax.devices()``; pass described devices (e.g. a
+    TPU topology's) to compile for a chip that is not attached.
+    """
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_cli_mesh(spec: str, *, num_processes: int = 1):
@@ -152,7 +165,7 @@ def make_cli_mesh(spec: str, *, num_processes: int = 1):
             f"mesh {spec} needs {total} devices but jax sees "
             f"{jax.device_count()} across {jax.process_count()} processes")
     axes = ("pod", "data", "model") if len(dims) == 3 else ("data", "model")
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -161,12 +174,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(n_data: int = 1, n_model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 # hardware constants for the roofline (TPU v5e per chip)

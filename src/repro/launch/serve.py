@@ -66,6 +66,7 @@ from repro.checkpoint.manager import (CheckpointManager, _flatten, _put,
 from repro.config import MultiLevelConfig
 from repro.configs import get_config
 from repro.core import operators as ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.paging import NULL_PAGE, BlockAllocator
 from repro.models import lm as lm_lib
 from repro.models.api import (build_model, make_paged_decode_step,
@@ -103,23 +104,29 @@ def _bucket(n: int, cap: Optional[int] = None) -> int:
     return min(b, cap) if cap is not None else b
 
 
-def make_write_prompt(page_size: int):
+def make_write_prompt(pool_specs):
     """Scatter a prefill cache ([layers, 1, L, ...] leaves) into a page pool
-    at ``page_ids`` ([n_pg] int32, logical page order).  Shared by the paged
-    engine's cold-prompt path and the speculative draft cache."""
+    at ``page_ids`` ([n_pg] int32, logical page order).  ``pool_specs`` is
+    the pool's Spec tree: each leaf's "page_seq" axis says where its page
+    dim sits (head-major GQA K/V, page-major MLA latents).  Shared by the
+    paged engine's cold-prompt path and the speculative draft cache."""
+    page_axes = jax.tree.map(lambda s: s.axes.index("page_seq"), pool_specs,
+                             is_leaf=is_spec)
 
     def write_prompt(pages, prefill_cache, page_ids):
         n_pg = page_ids.shape[0]
 
-        def one(pool, c):
+        def one(pool, c, ax):
+            P = pool.shape[ax]
             c = c[:, 0]  # [layers, L, ...]
             pad = [(0, 0)] * c.ndim
-            pad[1] = (0, n_pg * page_size - c.shape[1])
+            pad[1] = (0, n_pg * P - c.shape[1])
             c = jnp.pad(c, pad)
-            c = c.reshape(c.shape[0], n_pg, page_size, *c.shape[2:])
+            c = c.reshape(c.shape[0], n_pg, P, *c.shape[2:])
+            c = jnp.moveaxis(c, 2, ax)
             return pool.at[:, page_ids].set(c.astype(pool.dtype))
 
-        return jax.tree.map(one, pages, prefill_cache)
+        return jax.tree.map(one, pages, prefill_cache, page_axes)
 
     return write_prompt
 
@@ -235,8 +242,9 @@ class SpeculativePolicy(DecodePolicy):
         self.draft_step = jax.jit(make_paged_decode_step(self.draft_model),
                                   donate_argnums=(1,))
         self.verify = jax.jit(make_verify_step(eng.model), donate_argnums=(1,))
-        self._write_draft = jax.jit(make_write_prompt(eng.page_size),
-                                    donate_argnums=(0,))
+        self._write_draft = jax.jit(
+            make_write_prompt(self.draft_model.paged_cache_specs(
+                1, eng.page_size)), donate_argnums=(0,))
         # the draft cache gets its own pool, sized one worst-case table per
         # batch row (+ null page) so draft admission can never fail while a
         # row is free -- no un-admit path to maintain
@@ -810,8 +818,9 @@ class PagedServer(EngineCore):
         self.n_pages = n_pages
         self.paged_step = jax.jit(make_paged_decode_step(self.model),
                                   donate_argnums=(1,))
-        self._write_prompt = jax.jit(make_write_prompt(page_size),
-                                     donate_argnums=(0,))
+        self._write_prompt = jax.jit(
+            make_write_prompt(self.model.paged_cache_specs(n_pages, page_size)),
+            donate_argnums=(0,))
         self.pages = zeros_paged_cache(cfg, n_pages, page_size)
         self.alloc = BlockAllocator(n_pages, page_size, prefix_reuse=prefix_reuse)
         self.tables: List[Optional[List[int]]] = [None] * batch
@@ -827,16 +836,28 @@ class PagedServer(EngineCore):
             # compiled step's layout is.
             from jax.sharding import NamedSharding, PartitionSpec
 
-            from repro.distributed import put_global_tree
+            from repro.distributed import mesh_ctx, put_global_tree
 
-            psh, csh, _ = serve_shardings(self.model, mesh, n_pages=n_pages,
-                                          page_size=page_size,
-                                          rules=shard_rules)
+            psh, csh, rules = serve_shardings(self.model, mesh,
+                                              n_pages=n_pages,
+                                              page_size=page_size,
+                                              rules=shard_rules)
             repl = NamedSharding(mesh, PartitionSpec())
-            self.paged_step = jax.jit(make_paged_decode_step(self.model),
-                                      in_shardings=(psh, csh, repl, repl, repl),
-                                      out_shardings=(repl, csh),
-                                      donate_argnums=(1,))
+            step = jax.jit(make_paged_decode_step(self.model),
+                           in_shardings=(psh, csh, repl, repl, repl),
+                           out_shardings=(repl, csh), donate_argnums=(1,))
+            prefill = self.prefill
+
+            # traced under the serve layout's mesh context: Pallas kernels
+            # (paged decode, flash prefill) then run per shard
+            def in_mesh(fn):
+                def call(*args):
+                    with mesh_ctx(mesh, rules):
+                        return fn(*args)
+                return call
+
+            self.paged_step = in_mesh(step)
+            self.prefill = in_mesh(prefill)
             self._param_shardings = psh
             self.params = put_global_tree(self.params, psh)
             self.pages = put_global_tree(self.pages, csh)
@@ -993,7 +1014,9 @@ def make_server(cfg, engine: str = "paged", batch: int = 4, max_seq: int = 128,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced same-family config (--no-smoke: full size)")
     ap.add_argument("--engine", choices=ENGINES, default="paged")
     ap.add_argument("--policy", choices=POLICIES, default="greedy")
     ap.add_argument("--draft-k", type=int, default=4)
@@ -1018,6 +1041,7 @@ def main() -> None:
     ap.add_argument("--poll-every", type=int, default=1,
                     help="poll the reload manifest every N scheduler ticks")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

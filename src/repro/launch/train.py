@@ -74,6 +74,7 @@ from repro.data import MarkovLM, lm_batch, masked_lm_batch, vision_batch
 from repro.distributed import (any_process_flag, as_global_batch_fn,
                                batch_like, batch_shardings, data_shard_index,
                                is_primary, mesh_ctx, put_global_tree)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import init_distributed, make_cli_mesh, parse_mesh_arg
 from repro.models.api import (build_model, init_train_state, make_train_step,
                               train_state_shardings, zero_train_state)
@@ -609,6 +610,7 @@ def main() -> None:
                          "(family hooks, coalesced/protected axes, carried "
                          "fields) and exit without training")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # multi-process bring-up, then the mesh, must both happen before ANY
     # device-touching jax call: distributed init selects the gloo CPU

@@ -10,9 +10,10 @@ Three core computations:
 
 ``attn_impl="pallas"`` additionally dispatches train/prefill attention through
 the kernel registry (repro.kernels.dispatch) to the Pallas flash kernels --
-forward AND backward (custom VJP) -- with the XLA flash recipe below as the
-fallback for shapes the tiling cannot cover.  Both flash paths assume query
-positions 0..S-1 (train/prefill); decode uses plain attention.
+forward AND backward (custom VJP); sequence lengths the blocks do not divide
+are padded.  The XLA flash recipe below remains for the shapes the kernel
+cannot express (causal S != T, padded non-causal keys).  Both flash paths
+assume query positions 0..S-1 (train/prefill); decode uses plain attention.
 
 All attention math runs in fp32 softmax with bf16 matmul inputs (TPU MXU style).
 """
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.distributed import shard_l
 from repro.kernels import dispatch as kdispatch
+from repro.kernels.flash_attention import flash_tiles
 from repro.layers.basic import apply_rope, rms_norm
 from repro.param import Spec
 
@@ -34,23 +36,28 @@ NEG_INF = -1e30
 
 
 def paged_write(pages: jax.Array, new: jax.Array, positions: jax.Array,
-                block_tables: jax.Array) -> jax.Array:
-    """Scatter ``new`` [B,S,...] into ``pages`` [N,P,...] at absolute
-    ``positions`` [B,S] routed through per-sequence ``block_tables`` [B,M].
+                block_tables: jax.Array, seq_axis: int = 1) -> jax.Array:
+    """Scatter ``new`` [B,S,...] into ``pages`` at absolute ``positions``
+    [B,S] routed through per-sequence ``block_tables`` [B,M].  The pool is
+    [N,P,...] (``seq_axis=1``), or head-major [N,KH,P,D] (``seq_axis=2``,
+    the GQA K/V pool) with ``new`` [B,S,KH,D].
 
     Touches only the pages the written tokens land in -- admission/decode
     cost scales with the request, not with the pool.  Position -1 marks a
     padding slot (bucketed extend steps left-pad); its write is routed to
     page 0, the pool's reserved null page that no request ever owns.
     """
-    P = pages.shape[1]
+    P = pages.shape[seq_axis]
     valid = positions >= 0
     pos = jnp.maximum(positions, 0)
     page_ix = jnp.minimum(pos // P, block_tables.shape[1] - 1)
     pid = jnp.take_along_axis(block_tables, page_ix, axis=1)
     pid = jnp.where(valid, pid, 0)
     off = jnp.where(valid, pos % P, 0)
-    return pages.at[pid, off].set(new.astype(pages.dtype))
+    # advanced indices split by slices put the [B,S] dims first: the target
+    # of a head-major write is [B,S,KH,D], the layout of ``new``
+    idx = (pid,) + (slice(None),) * (seq_axis - 1) + (off,)
+    return pages.at[idx].set(new.astype(pages.dtype))
 
 
 def seq_masked_write(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
@@ -310,13 +317,6 @@ def _flash_xla_bwd(causal, scale, block_k, res, do):
 flash_xla.defvjp(_flash_xla_fwd, _flash_xla_bwd)
 
 
-def _largest_divisor(n: int, pref: int) -> int:
-    b = min(pref, n)
-    while n % b:
-        b -= 1
-    return b
-
-
 def _flash_pallas(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
                   backend: str) -> jax.Array:
     """Adapter from the layer layout [B,S,KH,G,D] to the kernel's [B,H,S,D].
@@ -353,13 +353,17 @@ def run_attention(q, k, v, cfg: ModelConfig, *, causal: bool, scale: float,
         # TPU, the interpreter off-TPU unless the config/env pins "xla".
         backend = kdispatch.resolve_backend(
             "flash_attention", cfg.kernel_backend or None, default="pallas")
-        bq = _largest_divisor(S, 128)
-        bk = _largest_divisor(T, min(cfg.attn_block_k, 128))
-        tileable = bq >= 8 and bk >= 8 and (not causal or S == T)
-        if backend != "xla" and tileable:
+        bk = min(cfg.attn_block_k, 128)
+        expressible = (S == T if causal else
+                       flash_tiles(S, T, 128, bk, causal, q.dtype) is not None)
+        if backend != "xla" and expressible:
             return _flash_pallas(q, k, v, causal=causal, scale=scale,
-                                 bq=bq, bk=bk, backend=backend)
-        # fall through: the XLA flash recipe below is the same algorithm
+                                 bq=128, bk=bk, backend=backend)
+        # fall through to the XLA flash recipe below (the same algorithm):
+        # the kernel assumes causal queries at 0..S-1 over keys 0..S-1 and
+        # has no key mask for padded non-causal keys.  Noted, so a run can
+        # tell which implementation it traced.
+        kdispatch.note("flash_attention", "xla")
     if impl in ("blockwise", "pallas", "pairs"):
         # memory-optimal custom-VJP path (flash recipe at the XLA level)
         return flash_xla(q, k, v, causal, scale, cfg.attn_block_k)
@@ -400,21 +404,22 @@ def gqa_cache_specs(cfg: ModelConfig, batch: int, max_seq: int) -> Dict[str, Spe
 
 
 def gqa_paged_cache_specs(cfg: ModelConfig, n_pages: int, page_size: int) -> Dict[str, Spec]:
-    """Page-pool K/V leaves: ``[n_pages, page_size, KH, D]`` shared across all
-    sequences (block tables route each sequence to its pages)."""
+    """Page-pool K/V leaves: ``[n_pages, KH, page_size, D]`` shared across all
+    sequences (block tables route each sequence to its pages).  Head-major,
+    so one (page, head) is a ``[page_size, D]`` tile for the decode kernel."""
     KH, D = cfg.n_kv_heads, cfg.resolved_head_dim
-    ax = ("pages", "page_seq", "cache_kv_heads", "head_dim")
+    ax = ("pages", "cache_kv_heads", "page_seq", "head_dim")
     dt = cfg.compute_dtype
     return {
-        "k": Spec((n_pages, page_size, KH, D), ax, init="zeros", dtype=dt),
-        "v": Spec((n_pages, page_size, KH, D), ax, init="zeros", dtype=dt),
+        "k": Spec((n_pages, KH, page_size, D), ax, init="zeros", dtype=dt),
+        "v": Spec((n_pages, KH, page_size, D), ax, init="zeros", dtype=dt),
     }
 
 
 def _paged_gqa_attention(qg, cache_k, cache_v, cfg: ModelConfig, *,
                          positions: jax.Array, block_tables: jax.Array,
                          scale: float) -> jax.Array:
-    """qg: [B,S,KH,G,D] against paged K/V [N,P,KH,D] -> [B,S,KH,G,D].
+    """qg: [B,S,KH,G,D] against paged K/V [N,KH,P,D] -> [B,S,KH,G,D].
 
     S == 1 (decode) dispatches to the registered ``paged_attention_decode``
     op; S > 1 (prefix-extend prefill) gathers the table's pages and runs the
@@ -422,7 +427,7 @@ def _paged_gqa_attention(qg, cache_k, cache_v, cfg: ModelConfig, *,
     batch actually spans), not with the server-wide max_seq.
     """
     B, S = qg.shape[:2]
-    P = cache_k.shape[1]
+    _, KH, P, D = cache_k.shape
     M = block_tables.shape[1]
     if S == 1:
         lengths = positions[:, -1] + 1  # the just-written token is attendable
@@ -432,8 +437,9 @@ def _paged_gqa_attention(qg, cache_k, cache_v, cfg: ModelConfig, *,
         out = kdispatch.get_impl("paged_attention_decode", backend)(
             qg[:, 0], cache_k, cache_v, block_tables, lengths, scale=scale)
         return out[:, None]
-    k = cache_k[block_tables].reshape(B, M * P, *cache_k.shape[2:])
-    v = cache_v[block_tables].reshape(B, M * P, *cache_v.shape[2:])
+    k = cache_k[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, M * P, KH, D)
+    v = cache_v[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+        B, M * P, KH, cache_v.shape[-1])
     return plain_attention(qg, k, v, causal=True, scale=scale,
                            q_positions=positions)
 
@@ -478,8 +484,8 @@ def gqa_apply(
         # paged decode/extend: write the new tokens' K/V into their pages,
         # then attend through the block table (single-host serving path --
         # the pool is not mesh-sharded, so no shard_l constraints here)
-        ck = paged_write(cache["k"], k, positions, block_tables)
-        cv = paged_write(cache["v"], v, positions, block_tables)
+        ck = paged_write(cache["k"], k, positions, block_tables, seq_axis=2)
+        cv = paged_write(cache["v"], v, positions, block_tables, seq_axis=2)
         qg = q.reshape(B, S, KH, H // KH, D)
         out = _paged_gqa_attention(qg, ck, cv, cfg, positions=positions,
                                    block_tables=block_tables, scale=D ** -0.5)

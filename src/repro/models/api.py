@@ -182,7 +182,6 @@ def _make_shardmap_train_step(model: Model, tc: TrainConfig, grad_reduce, mesh):
     the "model" axis is replicated inside the body (tensor parallelism stays a
     pjit concern; this path targets the data/DCN reduction).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import no_constraints
@@ -235,11 +234,11 @@ def _make_shardmap_train_step(model: Model, tc: TrainConfig, grad_reduce, mesh):
             return logical_spec(x.shape, axes, mesh)
 
         bspec = jax.tree.map(bspec_one, batch)
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, ospec, efspec, bspec),
             out_specs=(pspec, ospec, efspec, P()),
-            check_rep=False)
+            check_vma=False)
         return f(params, opt_state, ef, batch)
 
     return train_step

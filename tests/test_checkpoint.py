@@ -11,6 +11,7 @@ import pytest
 
 from helpers import batch_for, fast_tc, tiny_dense
 from repro.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model, init_train_state, make_train_step
 
 
@@ -146,7 +147,7 @@ def test_elastic_restore_onto_mesh(tmp_path):
     cm = CheckpointManager(str(tmp_path))
     st = {"params": {"w": jnp.arange(16.0).reshape(4, 4)}}
     cm.save(1, st, meta={"step": 1})
-    mesh = jax.make_mesh((1, 1), ("data", "model"))  # 1-device container
+    mesh = make_mesh((1, 1), ("data", "model"))  # 1-device container
     sh = {"params": {"w": NamedSharding(mesh, P("data", None))}}
     out, _ = cm.restore(jax.tree.map(jnp.zeros_like, st), shardings=sh)
     assert out["params"]["w"].sharding == sh["params"]["w"]

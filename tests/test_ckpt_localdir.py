@@ -89,6 +89,7 @@ def test_two_process_local_dirs_resume_on_one_process(tmp_path):
     res = run_multiprocess("""
         import os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.core.vcycle import VCycleRunner
@@ -99,7 +100,7 @@ def test_two_process_local_dirs_resume_on_one_process(tmp_path):
             pass
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
         # BOTH paths from the same run: a shared-dir manager (the reference
@@ -215,6 +216,7 @@ def test_one_process_local_save_resumes_on_two_processes(tmp_path):
     res = run_multiprocess("""
         import os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.core.vcycle import VCycleRunner
@@ -222,7 +224,7 @@ def test_one_process_local_save_resumes_on_two_processes(tmp_path):
         from repro.launch.train import make_batch_fn, restore_vcycle_state
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
         # rank 0 owns the dir that saved; rank 1's dir is fresh and empty

@@ -7,6 +7,7 @@ import pytest
 
 from repro.distributed.compression import (dequantize_int8, ef_compress,
                                            ef_int8_psum, init_ef_state, quantize_int8)
+from repro.launch.mesh import make_mesh
 
 
 def test_quantization_error_bound():
@@ -73,16 +74,13 @@ def test_error_feedback_unbiased_over_time():
 
 
 def test_shardmap_psum_single_device():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grads = {"w": jnp.ones((8, 8)) * 0.5}
     ef = init_ef_state(grads)
 
-    # jax.shard_map landed after 0.4.37; use the experimental home it has there
-    from jax.experimental.shard_map import shard_map
-
     @jax.jit
     def run(g, e):
-        return shard_map(
+        return jax.shard_map(
             lambda g, e: ef_int8_psum(g, e, "data"), mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
             out_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),

@@ -1,7 +1,12 @@
 """Kernel dispatch subsystem: registry resolution, Pallas flash attention
 forward AND backward parity (interpret mode), end-to-end ``attn_impl="pallas"``
-execution, and fused-vs-matrix equivalence of the level-transition operators
-on a full parameter tree."""
+execution, per-shard kernels under a mesh, and fused-vs-matrix equivalence of
+the level-transition operators on a full parameter tree."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,8 +60,8 @@ def test_pallas_downgrades_to_interpret_off_tpu():
 def _paged_case(key=0, B=3, KH=2, G=2, D=16, N=12, P=8, M=3):
     ks = jax.random.split(jax.random.PRNGKey(key), 3)
     q = jax.random.normal(ks[0], (B, KH, G, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (N, P, KH, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (N, P, KH, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (N, KH, P, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (N, KH, P, D), jnp.float32)
     # distinct pages per row; row 2 idle (length 0, table all null-page)
     bt = jnp.array([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
     lengths = jnp.array([3 * P, P + 3, 0], jnp.int32)  # full / partial / idle
@@ -243,18 +248,92 @@ def test_fused_cd_identity_pallas_interpret(monkeypatch):
     assert _tree_err(rt, p_small) <= 1e-5
 
 
-def test_coalesce_pair_degenerate_dims_fall_back_to_xla():
-    """Odd/prime dims collapse divisor_block to 1; the pallas backends must
-    hand those to the XLA implementation (and stay correct)."""
+def test_coalesce_pair_degenerate_dims_fall_back_to_xla(monkeypatch):
+    """Odd/prime dims used to leave no aligned tile and fall back to XLA.
+    They no longer do: the Pallas kernel pads them (each half of the paired
+    dim, the tail of the other) and stays correct, so a ``pallas`` op always
+    runs its kernel."""
+    calls = []
+    orig = dispatch.coalesce_pair_xla
+    monkeypatch.setattr(dispatch, "coalesce_pair_xla",
+                        lambda *a, **kw: (calls.append(1), orig(*a, **kw))[1])
     w = jax.random.normal(jax.random.PRNGKey(4), (514, 6), jnp.float32)  # 257 prime
     got = dispatch.dispatch("coalesce_pair", w, axis=0, w0=0.5,
                             backend="pallas-interpret")
     want = ref.coalesce_pair_ref(w, axis=0, w0=0.5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-    # prime non-projected dim takes the same guard
+    # prime non-projected dim, padded at its tail
     w2 = jax.random.normal(jax.random.PRNGKey(5), (257, 8), jnp.float32)
     got2 = dispatch.dispatch("coalesce_pair", w2, axis=1, w0=1.0,
                              backend="pallas-interpret")
     np.testing.assert_allclose(np.asarray(got2),
                                np.asarray(ref.coalesce_pair_ref(w2, axis=1, w0=1.0)),
                                atol=1e-5)
+    # a paired half with no aligned divisor above the block: (2*600, 130)
+    w3 = jax.random.normal(jax.random.PRNGKey(6), (1200, 130), jnp.float32)
+    got3 = dispatch.dispatch("coalesce_pair", w3, axis=0, w0=0.5, block=128,
+                             backend="pallas-interpret")
+    np.testing.assert_allclose(np.asarray(got3),
+                               np.asarray(ref.coalesce_pair_ref(w3, axis=0, w0=0.5)),
+                               atol=1e-5)
+    assert not calls
+
+
+def test_kernels_run_per_shard_under_a_mesh():
+    """Under ``mesh_ctx`` every Pallas op runs per shard in ``shard_map``
+    (XLA cannot partition a Mosaic kernel): on a 2x2 mesh of host devices,
+    with sharded inputs inside jit, each op (flash fwd and grads too) equals
+    the unsharded kernel.  Subprocess: this process keeps one CPU device."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.distributed import mesh_ctx
+        from repro.kernels import dispatch
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 2), ("data", "model"))
+        rules = {"cache_kv_heads": "model"}
+        ks = iter(jax.random.split(jax.random.PRNGKey(0), 12))
+        rnd = lambda *s: jax.random.normal(next(ks), s, jnp.float32)
+        q, k, v = rnd(2, 4, 256, 16), rnd(2, 4, 256, 16), rnd(2, 4, 256, 16)
+        bt = jnp.array([[1, 2, 3], [4, 5, 0]], jnp.int32)
+        cases = {
+            "flash_attention": (lambda q, k, v: jax.value_and_grad(
+                lambda q, k, v: jnp.sum(dispatch.dispatch(
+                    "flash_attention", q, k, v, causal=True, block_q=64,
+                    block_k=64, backend="pallas-interpret") ** 2),
+                argnums=(0, 1, 2))(q, k, v), (q, k, v)),
+            "coalesce_pair": (lambda w: dispatch.dispatch(
+                "coalesce_pair", w, axis=0, w0=0.5,
+                backend="pallas-interpret"), (rnd(24, 256),)),
+            "interp_axpy": (lambda a, b: dispatch.dispatch(
+                "interp_axpy", a, b, 0.25, backend="pallas-interpret"),
+                (rnd(8, 96), rnd(8, 96))),
+            "paged_attention_decode": (lambda q, kp, vp: dispatch.dispatch(
+                "paged_attention_decode", q, kp, vp, bt,
+                jnp.array([20, 11], jnp.int32), backend="pallas-interpret"),
+                (rnd(2, 4, 2, 16), rnd(6, 4, 8, 16), rnd(6, 4, 8, 16))),
+        }
+        for op, (fn, args) in cases.items():
+            want = jax.jit(fn)(*args)
+            placed = [jax.device_put(a, NamedSharding(mesh, P(*(
+                ("data",) if a.shape[0] % 2 == 0 else (None,)))))
+                      for a in args]
+            with mesh_ctx(mesh, rules):
+                got = jax.jit(fn)(*placed)
+                assert "shard_map" in str(jax.make_jaxpr(fn)(*placed)), op
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+                np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                           atol=1e-5, rtol=1e-5, err_msg=op)
+        print("PER_SHARD_OK")
+    """)
+    env = dict(os.environ, PYTHONPATH="src" + os.pathsep + "tests",
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env,
+                         cwd=os.path.join(os.path.dirname(__file__), ".."),
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "PER_SHARD_OK" in out.stdout

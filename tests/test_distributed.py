@@ -13,6 +13,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import RULES, logical_spec
+from repro.launch.mesh import make_mesh
 
 
 class FakeMesh:
@@ -67,7 +68,7 @@ def test_batch_axis_sharding_divisibility():
 def test_batch_shardings_tree():
     from repro.distributed import batch_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     like = {"tokens": jax.ShapeDtypeStruct((4, 16), np.int32),
             "labels": jax.ShapeDtypeStruct((4, 16), np.int32)}
     sh = batch_shardings(like, mesh)
@@ -81,7 +82,7 @@ def test_data_shard_index_single_process():
     from repro.distributed import data_shard_index
 
     assert data_shard_index() == jax.process_index() == 0
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert data_shard_index(mesh) == 0
 
 
@@ -112,6 +113,7 @@ def test_cross_mesh_vcycle_restore_equivalence(tmp_path):
         from repro.config import MultiLevelConfig
         from repro.core.vcycle import VCycleRunner
         from repro.data import MarkovLM, lm_batch
+        from repro.launch.mesh import make_mesh
         from repro.launch.train import make_vcycle_save_cb, restore_vcycle_state
 
         class Preempted(RuntimeError):
@@ -136,7 +138,7 @@ def test_cross_mesh_vcycle_restore_equivalence(tmp_path):
         for k, (shape_a, shape_b) in enumerate([((1, 1), (2, 2)),
                                                 ((2, 2), (1, 1))]):
             ckdir = f"{os.environ['CK_BASE']}/pair{k}"
-            mesh_a = jax.make_mesh(shape_a, ("data", "model"))
+            mesh_a = make_mesh(shape_a, ("data", "model"))
             runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh_a)
             cm = CheckpointManager(ckdir)
             save_cb = make_vcycle_save_cb(cm, schedule=runner.plan)
@@ -153,7 +155,7 @@ def test_cross_mesh_vcycle_restore_equivalence(tmp_path):
                 pass
             cm.wait()
 
-            mesh_b = jax.make_mesh(shape_b, ("data", "model"))
+            mesh_b = make_mesh(shape_b, ("data", "model"))
             runner2 = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh_b)
             state, params, opt = restore_vcycle_state(cm, runner2, tc)
             assert (state.phase, state.level, state.global_step) == ("up", 1, 6)
@@ -204,6 +206,7 @@ def test_reduced_dryrun_subprocess(tmp_path):
         from repro.configs import get_config
         from repro.distributed import param_shardings, set_mesh_ctx
         from repro.launch.analysis import analyze_compiled, memory_summary
+        from repro.launch.mesh import make_mesh
         from repro.models.api import build_model, make_train_step
         from repro.optim import adamw_init_specs
         from repro.param import struct_tree
@@ -211,7 +214,7 @@ def test_reduced_dryrun_subprocess(tmp_path):
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         set_mesh_ctx(mesh)
         cfg = get_config("tinyllama-1.1b", smoke=True).replace(
             d_model=64, vocab_size=512)

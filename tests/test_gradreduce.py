@@ -29,6 +29,7 @@ from repro.distributed.compression import (dense_wire_bytes, ef_compress,
                                            reset_ef_psum_probe)
 from repro.distributed.reduce import (DenseReduce, HierarchicalInt8EF,
                                       make_grad_reduce)
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(autouse=True)
@@ -58,13 +59,11 @@ def _assert_trees(a, b, atol, err=""):
 
 
 def _shardmap_psum(grads, ef):
-    from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((1,), ("pod",))
-    return jax.jit(shard_map(
+    mesh = make_mesh((1,), ("pod",))
+    return jax.jit(jax.shard_map(
         lambda g, e: ef_int8_psum(g, e, "pod"), mesh=mesh,
         in_specs=(P(), P()), out_specs=(P(), P()),
-        check_rep=False))(grads, ef)
+        check_vma=False))(grads, ef)
 
 
 def test_packed_psum_matches_per_leaf_reference():
@@ -102,13 +101,12 @@ def test_packed_psum_is_two_collectives_total():
     """The whole point of packing: 2 collectives per step (one pmax over the
     stacked scales + one int32 psum over the concatenated payload) instead of
     2 per leaf."""
-    from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     grads = {f"l{i}": jnp.ones((4, 4)) for i in range(5)}
     ef = init_ef_state(grads)
-    f = shard_map(lambda g, e: ef_int8_psum(g, e, "pod"), mesh=mesh,
-                  in_specs=(P(), P()), out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(lambda g, e: ef_int8_psum(g, e, "pod"), mesh=mesh,
+                      in_specs=(P(), P()), out_specs=(P(), P()),
+                      check_vma=False)
     text = str(jax.make_jaxpr(f)(grads, ef))
     assert text.count("psum") == 1, text
     assert text.count("pmax") == 1, text
@@ -130,8 +128,8 @@ def test_wire_bytes_ratio_at_least_3x():
 
 
 def test_make_grad_reduce_factory():
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
-    mesh3 = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
+    mesh3 = make_mesh((1, 1, 1), ("pod", "data", "model"))
     assert make_grad_reduce("none", mesh2) is None
     assert make_grad_reduce("", mesh2) is None
     assert make_grad_reduce(None, mesh2) is None
@@ -147,7 +145,7 @@ def test_make_grad_reduce_factory():
 
     with pytest.raises(ValueError, match="unknown grad_compression"):
         make_grad_reduce("fp8", mesh2)
-    model_only = jax.make_mesh((1,), ("model",))
+    model_only = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="no data-like axis"):
         make_grad_reduce("dense", model_only)
 
@@ -165,7 +163,7 @@ def test_parse_mesh_arg_pod_axis():
 def test_ef_state_layout():
     """EF residuals: one [dcn_size, *param] f32 block per leaf, sharded over
     the DCN axis on dim 0 so each pod rank owns exactly its own residual."""
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     gr = HierarchicalInt8EF(data_axes=("pod", "data"), dcn_axis="pod",
                             ici_axes=("data",), dcn_size=2)
     params = {"w": jnp.zeros((8, 4)), "b": jnp.zeros((4,))}
@@ -193,7 +191,7 @@ def test_dense_shardmap_step_matches_legacy():
     from repro.models.api import build_model
 
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     batch = batch_for(cfg, B=4, S=16)
 
     p0, o0 = init_train_state(model, tc, jax.random.PRNGKey(0))
@@ -225,7 +223,7 @@ def test_int8ef_shardmap_step_tracks_dense():
                      compute_dtype=jnp.float32)
     tc = fast_tc(steps=4, batch_size=4, seq_len=16)
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     batch = batch_for(cfg, B=4, S=16)
     p0, o0 = init_train_state(model, tc, jax.random.PRNGKey(0))
 
@@ -260,7 +258,7 @@ def _vcycle_pieces(compression):
 
     cfg, tc, ml = mp_arena()
     tc = dataclasses.replace(tc, grad_compression=compression)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     bf = make_batch_fn(cfg, tc, shard=0)
     return cfg, tc, ml, mesh, bf, VCycleRunner
 
@@ -487,6 +485,7 @@ def test_two_process_int8ef_vcycle_tracks_dense(tmp_path):
     res = run_multiprocess("""
         import dataclasses, json, os
         import jax
+        from repro.launch.mesh import make_mesh
         import numpy as np
         from helpers import mp_arena
         from repro.core.vcycle import VCycleRunner
@@ -495,7 +494,7 @@ def test_two_process_int8ef_vcycle_tracks_dense(tmp_path):
         from repro.launch.train import make_batch_fn
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
+        mesh = make_mesh((2, 1, 1), ("pod", "data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
 
         dense = VCycleRunner(
@@ -532,6 +531,7 @@ def test_two_process_ef_state_survives_kill_and_resume(tmp_path):
     res = run_multiprocess("""
         import dataclasses, os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.core.vcycle import VCycleRunner
@@ -543,7 +543,7 @@ def test_two_process_ef_state_survives_kill_and_resume(tmp_path):
 
         cfg, tc, ml = mp_arena()
         tc = dataclasses.replace(tc, grad_compression="int8_ef")
-        mesh = jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
+        mesh = make_mesh((2, 1, 1), ("pod", "data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
 
         # uninterrupted reference, final params published for the outer test
@@ -574,6 +574,7 @@ def test_two_process_ef_state_survives_kill_and_resume(tmp_path):
     res = run_multiprocess("""
         import dataclasses, os
         import jax
+        from repro.launch.mesh import make_mesh
         import numpy as np
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
@@ -583,7 +584,7 @@ def test_two_process_ef_state_survives_kill_and_resume(tmp_path):
 
         cfg, tc, ml = mp_arena()
         tc = dataclasses.replace(tc, grad_compression="int8_ef")
-        mesh = jax.make_mesh((2, 1, 1), ("pod", "data", "model"))
+        mesh = make_mesh((2, 1, 1), ("pod", "data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
         cm = CheckpointManager(os.environ["CK"])
@@ -630,9 +631,10 @@ def test_two_process_localdir_restore_fetches_only_addressed_slices(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import CheckpointManager
         from repro.distributed import put_global_tree
+        from repro.launch.mesh import make_mesh
 
         pid = jax.process_index()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         sh_w = NamedSharding(mesh, P("data"))
         sh_b = NamedSharding(mesh, P())
         w = np.arange(32, dtype=np.float32).reshape(4, 8)

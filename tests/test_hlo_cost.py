@@ -29,8 +29,6 @@ def test_scan_multiplies_trip_count():
     assert a["flops"] == pytest.approx(7 * 2 * 128 ** 3, rel=0.01)
     # ...and document why this module exists: XLA counts the body once
     xla = compiled.cost_analysis()
-    if isinstance(xla, (list, tuple)):  # jax<=0.4.x returns [dict]
-        xla = xla[0]
     assert xla["flops"] < a["flops"] / 2
 
 

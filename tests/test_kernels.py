@@ -46,6 +46,32 @@ def test_flash_attention_block_invariance(block):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_pads_unaligned_lengths(dtype):
+    """A causal length no aligned block divides (200) is padded, not handed
+    to XLA: values and grads still match the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(10), 4)
+    q, k, v, ct = (jax.random.normal(kk, (1, 2, 200, 32), dtype) for kk in ks)
+    got = ops.flash_attention_vjp(q, k, v, causal=True, block_q=64, block_k=64)
+    want = ref.naive_attention(q, k, v, causal=True)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * ct.astype(jnp.float32))
+
+    g = jax.grad(loss(lambda q, k, v: ops.flash_attention_vjp(
+        q, k, v, causal=True, block_q=64, block_k=64)), argnums=(0, 1, 2))(q, k, v)
+    w = jax.grad(loss(lambda q, k, v: ref.naive_attention(q, k, v, causal=True)),
+                 argnums=(0, 1, 2))(q, k, v)
+    gtol = 6e-2 if dtype == jnp.bfloat16 else 1e-4
+    for a, b in zip(g, w):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=gtol, rtol=gtol)
+
+
 @pytest.mark.parametrize("backend", RESOLVABLE)
 @pytest.mark.parametrize("shape", [(8, 8), (512, 384), (64, 640), (768, 64)])
 @pytest.mark.parametrize("axis", [0, 1])
@@ -113,8 +139,8 @@ def test_paged_attention_matches_dense_reassembly(backend, dtype):
     B, KH, G, D, N, P, M = 2, 2, 3, 32, 10, 8, 3
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, KH, G, D), dtype)
-    k_pages = jax.random.normal(ks[1], (N, P, KH, D), dtype)
-    v_pages = jax.random.normal(ks[2], (N, P, KH, D), dtype)
+    k_pages = jax.random.normal(ks[1], (N, KH, P, D), dtype)
+    v_pages = jax.random.normal(ks[2], (N, KH, P, D), dtype)
     bt = jnp.array([[7, 2, 9], [4, 1, 0]], jnp.int32)  # row 1: padded tail
     lengths = jnp.array([3 * P, P + 5, ], jnp.int32)
     got = dispatch.dispatch("paged_attention_decode", q, k_pages, v_pages,
@@ -124,8 +150,8 @@ def test_paged_attention_matches_dense_reassembly(backend, dtype):
     outs = []
     for b in range(B):
         L = int(lengths[b])
-        k = k_pages[bt[b]].reshape(M * P, KH, D)[:L]
-        v = v_pages[bt[b]].reshape(M * P, KH, D)[:L]
+        k = k_pages[bt[b]].transpose(0, 2, 1, 3).reshape(M * P, KH, D)[:L]
+        v = v_pages[bt[b]].transpose(0, 2, 1, 3).reshape(M * P, KH, D)[:L]
         # [1, KH, G, D] x [1, KH, L, D] via the naive oracle's B,H,S,T layout
         o = ref.naive_attention(q[b][None].reshape(1, KH * G, 1, D).astype(jnp.float32),
                                 jnp.repeat(k.transpose(1, 0, 2), G, axis=0)[None].astype(jnp.float32),
@@ -145,8 +171,8 @@ def test_paged_attention_table_padding_ignored(backend):
     B, KH, G, D, N, P = 1, 2, 2, 16, 6, 4
     ks = jax.random.split(jax.random.PRNGKey(8), 3)
     q = jax.random.normal(ks[0], (B, KH, G, D), jnp.float32)
-    k_pages = jax.random.normal(ks[1], (N, P, KH, D), jnp.float32)
-    v_pages = jax.random.normal(ks[2], (N, P, KH, D), jnp.float32)
+    k_pages = jax.random.normal(ks[1], (N, KH, P, D), jnp.float32)
+    v_pages = jax.random.normal(ks[2], (N, KH, P, D), jnp.float32)
     lengths = jnp.array([2 * P - 1], jnp.int32)
     narrow = dispatch.dispatch("paged_attention_decode", q, k_pages, v_pages,
                                jnp.array([[3, 5]], jnp.int32), lengths, backend=backend)
@@ -157,14 +183,16 @@ def test_paged_attention_table_padding_ignored(backend):
 
 def test_paged_attention_ops_wrapper():
     """The jit'd public wrapper resolves interpret mode off-TPU and agrees
-    with the gather reference."""
+    with the gather reference (which reads the page-major [N, P, KH, D]
+    layout of the head-major pool)."""
     q, kp, vp = (jax.random.normal(k, s, jnp.float32) for k, s in zip(
         jax.random.split(jax.random.PRNGKey(9), 3),
-        [(2, 2, 2, 16), (8, 4, 2, 16), (8, 4, 2, 16)]))
+        [(2, 2, 2, 16), (8, 2, 4, 16), (8, 2, 4, 16)]))
     bt = jnp.array([[1, 2], [3, 0]], jnp.int32)
     lengths = jnp.array([7, 4], jnp.int32)
     got = ops.paged_attention_decode(q, kp, vp, bt, lengths)
-    want = ref.paged_attention_ref(q, kp, vp, bt, lengths)
+    want = ref.paged_attention_ref(q, jnp.swapaxes(kp, 1, 2),
+                                   jnp.swapaxes(vp, 1, 2), bt, lengths)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 

@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from helpers import free_port, mp_arena, run_multiprocess
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -41,7 +42,7 @@ def test_single_process_helpers_degrade_to_noops():
     assert any_process_flag(True) is True
     assert any_process_flag(False) is False
     bf = lambda step: {"x": np.zeros((4, 2))}
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert as_global_batch_fn(bf, mesh) is bf  # identity, not a wrapper
     assert as_global_batch_fn(bf, None) is bf
 
@@ -91,7 +92,7 @@ def test_fused_drain_flag_single_mesh_mechanics():
     from repro.distributed import FusedDrainFlag
     from repro.launch.train import PreemptionGuard
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     g = PreemptionGuard()
     drain = g.attach(FusedDrainFlag(mesh, guard=g))
     assert g.should_stop() is False  # nothing observed yet
@@ -114,7 +115,7 @@ def test_fused_drain_guard_local_flag_before_first_step():
     from repro.distributed import FusedDrainFlag
     from repro.launch.train import PreemptionGuard
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     g = PreemptionGuard()
     g.attach(FusedDrainFlag(mesh, guard=g))
     g.triggered = True
@@ -167,13 +168,14 @@ def test_two_process_vcycle_matches_single_process(tmp_path):
     res = run_multiprocess("""
         import os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.distributed import mesh_ctx
         from repro.launch.train import train_vcycle_ckpt
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         cm = CheckpointManager(os.environ["CK"])
         with mesh_ctx(mesh):
             out = train_vcycle_ckpt(cfg, ml, tc, ckpt=cm, ckpt_every=4,
@@ -220,6 +222,7 @@ def test_checkpoint_crosses_process_counts_both_ways(tmp_path):
     res = run_multiprocess("""
         import os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.core.vcycle import VCycleRunner
@@ -230,7 +233,7 @@ def test_checkpoint_crosses_process_counts_both_ways(tmp_path):
             pass
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
         cm = CheckpointManager(os.environ["CK"])
@@ -282,6 +285,7 @@ def test_checkpoint_crosses_process_counts_both_ways(tmp_path):
     res = run_multiprocess("""
         import os
         import jax
+        from repro.launch.mesh import make_mesh
         from helpers import mp_arena
         from repro.checkpoint import CheckpointManager
         from repro.core.vcycle import VCycleRunner
@@ -289,7 +293,7 @@ def test_checkpoint_crosses_process_counts_both_ways(tmp_path):
         from repro.launch.train import make_batch_fn, restore_vcycle_state
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         runner = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
         cm = CheckpointManager(os.environ["CK"])
@@ -347,6 +351,7 @@ def test_fused_drain_no_dedicated_allgather(tmp_path):
     agreed global step."""
     res = run_multiprocess("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.experimental import multihost_utils as mh
         calls = {"n": 0}
         orig = mh.process_allgather
@@ -361,7 +366,7 @@ def test_fused_drain_no_dedicated_allgather(tmp_path):
         from repro.launch.train import PreemptionGuard, make_batch_fn
 
         cfg, tc, ml = mp_arena()
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         bf = as_global_batch_fn(make_batch_fn(cfg, tc, shard=0), mesh)
         guard = PreemptionGuard()
         drain = guard.attach(FusedDrainFlag(mesh, guard=guard))

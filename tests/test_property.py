@@ -11,6 +11,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import projections as proj
 from repro.core.vcycle import History, flops_to_reach
+from repro.launch.mesh import make_mesh
 
 even = st.integers(min_value=1, max_value=64).map(lambda k: 2 * k)
 
@@ -153,7 +154,7 @@ def test_checkpoint_roundtrip_across_shard_layouts(dedup, rows, seed):
     rng = np.random.default_rng(seed)
     w = rng.normal(size=(2 * rows, 4)).astype(np.float32)
     tree = {"params": {"w": w, "b": rng.normal(size=(4,)).astype(np.float16)}}
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = {"params": {"w": NamedSharding(mesh, P("data", None)),
                      "b": NamedSharding(mesh, P())}}
     with tempfile.TemporaryDirectory() as d:

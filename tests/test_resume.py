@@ -13,6 +13,7 @@ from repro.checkpoint import CheckpointManager
 from repro.config import MultiLevelConfig
 from repro.core.vcycle import SegmentPlan, VCycleRunner, segments
 from repro.data import MarkovLM, lm_batch
+from repro.launch.mesh import make_mesh
 from repro.launch.train import make_vcycle_save_cb, restore_vcycle_state
 
 
@@ -106,7 +107,7 @@ def test_restore_unsharded_save_onto_mesh(tmp_path):
         runner.run(ckpt_cb=killing_cb, ckpt_every=2)
     cm.wait()
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     runner2 = VCycleRunner(cfg, ml, tc, bf, seed=0, mesh=mesh)
     state, params, opt = restore_vcycle_state(cm, runner2, tc)
     for tree in (params, opt, state.params_before[0]):

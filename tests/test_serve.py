@@ -28,6 +28,7 @@ from helpers import tiny_dense, tiny_mla
 from repro.config import MultiLevelConfig
 from repro.configs import get_config
 from repro.core import operators as ops
+from repro.launch.mesh import make_mesh
 from repro.launch.serve import (EngineCore, PagedServer, Request, Server,
                                 SpeculativePolicy, make_server)
 from repro.models.api import build_model
@@ -387,7 +388,7 @@ def test_speculative_reset_and_reuse():
 
 def test_make_server_rejects_mesh_on_slots_engine():
     cfg = tiny_dense(compute_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="paged engine"):
         make_server(cfg, engine="slots", mesh=mesh)
 
@@ -404,6 +405,7 @@ def test_mesh_sharded_paged_decode_matches_unsharded():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import jax
+        from repro.launch.mesh import make_mesh
         import numpy as np
         from helpers import tiny_dense
         from repro.launch.serve import Request, make_server
@@ -422,7 +424,7 @@ def test_mesh_sharded_paged_decode_matches_unsharded():
 
         kw = dict(engine="paged", batch=3, max_seq=48, page_size=8)
         ref = make_server(cfg, **kw)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         srv = make_server(cfg, mesh=mesh, **kw)
 
         # the page pools are genuinely model-sharded, not replicated

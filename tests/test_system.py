@@ -385,3 +385,24 @@ def test_serve_soak_live_trainer_reloads(tmp_path):
         watcher.reload_history
     assert watcher.poll_errors == 0 or watcher.steps_seen, \
         "poll errors without a single landed step"
+
+
+@pytest.mark.parametrize("lone", [False, True], ids=["no_tpu", "lone_script"])
+def test_chip_smoke_refuses_to_run_off_chip(tmp_path, lone):
+    """chip_smoke.py has no CPU fallback: without a TPU (or copied out of
+    the checkout, with no package beside it) it exits non-zero and prints
+    no result line."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    script = os.path.join(root, "chip_smoke.py")
+    if lone:
+        script = str(tmp_path / "chip_smoke.py")
+        with open(os.path.join(root, "chip_smoke.py")) as src, \
+                open(script, "w") as dst:
+            dst.write(src.read())
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, script], capture_output=True,
+                         text=True, env=env, cwd=os.path.dirname(script),
+                         timeout=300)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert ("no TPU found" in out.stderr) != lone
