@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -143,11 +144,11 @@ def _train_loop(step_fn, batch_fn, steps: int, start_in_seg: int, params,
     seg_base = bisect.bisect_right(history.step, g - start_in_seg)
     for i in range(start_in_seg, steps):
         batch = batch_fn(g)
-        t0 = time.time()
+        t0 = time.perf_counter()
         params, opt_state, metrics = step_fn(params, opt_state, batch)
         if sync_every_step:
             jax.block_until_ready(metrics["loss"])
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
         cum += fps
         g += 1
         stop = False
@@ -196,6 +197,19 @@ def train_segment(
         step_fn, batch_fn, steps, 0, params, opt_state, history,
         start_flops, start_step, level, fps, tc.log_every, target_loss)
     return params, opt_state, history, cum, g
+
+
+def _host_span(fn: Callable, level: int) -> Callable:
+    """``fn`` with each call inside the host span ``repro.train_step``
+    (``level`` as its argument), on the profiler's clock; arguments,
+    donation and outputs pass through.  ``__wrapped__`` is ``fn``."""
+
+    @functools.wraps(fn)
+    def step(*args):
+        with jax.profiler.TraceAnnotation("repro.train_step", level=level):
+            return fn(*args)
+
+    return step
 
 
 # ---------------------------------------------------------------------------
@@ -401,6 +415,9 @@ class VCycleRunner:
                                        mesh=self.mesh)
             else:
                 step = make_train_step(self.models[level], self.tc)
+            # the compiled program, and so the device trace's module, is
+            # named by the level: jit_train_step_l0, jit_train_step_l1, ...
+            step.__name__ = step.__qualname__ = f"train_step_l{level}"
             if self.mesh is None:
                 fn = jax.jit(step, donate_argnums=(0, 1))
             else:
@@ -439,6 +456,7 @@ class VCycleRunner:
                                  in_shardings=(psh, osh, self.batch_shardings()),
                                  out_shardings=(psh, osh, rep),
                                  donate_argnums=(0, 1))
+            fn = _host_span(fn, level)
             self._step_fns[level] = fn
             self.n_compiles += 1
         return fn

@@ -357,6 +357,7 @@ class FusedDrainFlag:
             m["drain"] = self.reduce(flag)
             return (*outs, m)
 
+        fused.__name__ = fused.__qualname__ = step.__name__  # names the program
         compiled = jax.jit(fused,
                            in_shardings=(*in_shardings, self.sharding),
                            out_shardings=out_shardings,

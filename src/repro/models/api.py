@@ -62,8 +62,9 @@ class Model:
             lbl = batch["labels"]
             mtp_labels = jnp.concatenate(
                 [lbl[:, 1:], jnp.full_like(lbl[:, :1], -1)], axis=1)
-        return lm_lib.lm_loss(out["logits"], batch["labels"], cfg, out["aux"],
-                              out.get("mtp_logits"), mtp_labels, z_loss)
+        with jax.named_scope("loss"):
+            return lm_lib.lm_loss(out["logits"], batch["labels"], cfg, out["aux"],
+                                  out.get("mtp_logits"), mtp_labels, z_loss)
 
     def forward_logits(self, params, batch):
         if self.cfg.family == "vit":

@@ -127,62 +127,66 @@ def block_apply(
     decode = mode == "decode"
     prefill = mode == "prefill"
 
-    if mixer in ("attn", "enc_attn", "dec_attn"):
-        h = norm_apply(p["norm1"], x, cfg)
-        causal = mixer != "enc_attn"
-        self_cache = cache.get("self") if decode else None
-        if cfg.attn_type == "mla":
-            y, c_new = attn.mla_apply(p["mixer"], h, cfg, positions=positions,
-                                      causal=causal, cache=self_cache,
-                                      block_tables=block_tables)
-        else:
-            y, c_new = attn.gqa_apply(p["mixer"], h, cfg, positions=positions,
-                                      causal=causal, cache=self_cache,
-                                      block_tables=block_tables)
-        x = x + y
-        if prefill:
-            new_cache["self"] = _prefill_self_cache(p["mixer"], h, cfg, positions)
-        elif decode:
-            new_cache["self"] = c_new
-        if mixer == "dec_attn":
-            hx = norm_apply(p["norm_x"], x, cfg)
-            kv_cache = cache.get("cross") if decode else None
-            y = attn.cross_attn_apply(p["cross"], hx, cfg, kv_src=cross_src,
-                                      kv_cache=kv_cache, gated=False)
+    with jax.named_scope("ssm" if mixer in ("mamba", "mlstm", "slstm") else "attention"):
+        if mixer in ("attn", "enc_attn", "dec_attn"):
+            h = norm_apply(p["norm1"], x, cfg)
+            causal = mixer != "enc_attn"
+            self_cache = cache.get("self") if decode else None
+            if cfg.attn_type == "mla":
+                y, c_new = attn.mla_apply(p["mixer"], h, cfg, positions=positions,
+                                          causal=causal, cache=self_cache,
+                                          block_tables=block_tables)
+            else:
+                y, c_new = attn.gqa_apply(p["mixer"], h, cfg, positions=positions,
+                                          causal=causal, cache=self_cache,
+                                          block_tables=block_tables)
             x = x + y
             if prefill:
-                new_cache["cross"] = attn.cross_attn_precompute(p["cross"], cross_src, cfg)
+                new_cache["self"] = _prefill_self_cache(p["mixer"], h, cfg, positions)
+            elif decode:
+                new_cache["self"] = c_new
+            if mixer == "dec_attn":
+                hx = norm_apply(p["norm_x"], x, cfg)
+                kv_cache = cache.get("cross") if decode else None
+                y = attn.cross_attn_apply(p["cross"], hx, cfg, kv_src=cross_src,
+                                          kv_cache=kv_cache, gated=False)
+                x = x + y
+                if prefill:
+                    new_cache["cross"] = attn.cross_attn_precompute(p["cross"], cross_src, cfg)
+                elif decode:
+                    new_cache["cross"] = cache["cross"]
+        elif mixer == "cross_attn":
+            h = norm_apply(p["norm1"], x, cfg)
+            kv_cache = cache.get("cross") if decode else None
+            y = attn.cross_attn_apply(p["mixer"], h, cfg, kv_src=cross_src,
+                                      kv_cache=kv_cache, gated=True)
+            x = x + y
+            if prefill:
+                new_cache["cross"] = attn.cross_attn_precompute(p["mixer"], cross_src, cfg)
             elif decode:
                 new_cache["cross"] = cache["cross"]
-    elif mixer == "cross_attn":
-        h = norm_apply(p["norm1"], x, cfg)
-        kv_cache = cache.get("cross") if decode else None
-        y = attn.cross_attn_apply(p["mixer"], h, cfg, kv_src=cross_src,
-                                  kv_cache=kv_cache, gated=True)
-        x = x + y
-        if prefill:
-            new_cache["cross"] = attn.cross_attn_precompute(p["mixer"], cross_src, cfg)
-        elif decode:
-            new_cache["cross"] = cache["cross"]
-    elif mixer in ("mamba", "mlstm", "slstm"):
-        h = norm_apply(p["norm1"], x, cfg)
-        fn = {"mamba": ssm.mamba_apply, "mlstm": ssm.mlstm_apply, "slstm": ssm.slstm_apply}[mixer]
-        ssm_cache = cache.get("ssm") if decode else None
-        y, c_new = fn(p["mixer"], h, cfg, cache=ssm_cache, return_state=prefill)
-        if prefill or decode:
-            new_cache["ssm"] = c_new
-        x = x + y
-    else:
-        raise ValueError(mixer)
+        elif mixer in ("mamba", "mlstm", "slstm"):
+            h = norm_apply(p["norm1"], x, cfg)
+            fn = {"mamba": ssm.mamba_apply, "mlstm": ssm.mlstm_apply,
+                  "slstm": ssm.slstm_apply}[mixer]
+            ssm_cache = cache.get("ssm") if decode else None
+            y, c_new = fn(p["mixer"], h, cfg, cache=ssm_cache, return_state=prefill)
+            if prefill or decode:
+                new_cache["ssm"] = c_new
+            x = x + y
+        else:
+            raise ValueError(mixer)
 
     if bs.ffn == "dense":
-        h = norm_apply(p["norm2"], x, cfg)
-        x = x + ffn_lib.ffn_apply(p["ffn"], h, cfg)
+        with jax.named_scope("mlp"):
+            h = norm_apply(p["norm2"], x, cfg)
+            x = x + ffn_lib.ffn_apply(p["ffn"], h, cfg)
     elif bs.ffn == "moe":
-        h = norm_apply(p["norm2"], x, cfg)
-        y, a = ffn_lib.moe_apply(p["ffn"], h, cfg)
-        x = x + y
-        aux = aux + a
+        with jax.named_scope("moe"):
+            h = norm_apply(p["norm2"], x, cfg)
+            y, a = ffn_lib.moe_apply(p["ffn"], h, cfg)
+            x = x + y
+            aux = aux + a
     return x, (new_cache if (prefill or decode) else None), aux
 
 
@@ -359,8 +363,9 @@ def lm_forward(
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    x = embed_tokens(params["embed"], tokens, cfg)
-    x = shard_l(x, ("batch", "seq", "act_embed"))
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens, cfg)
+        x = shard_l(x, ("batch", "seq", "act_embed"))
 
     cross_src = None if img_embeds is None else img_embeds.astype(cfg.compute_dtype)
     if cfg.n_encoder_layers and mode != "decode":  # decode reads cross K/V from cache
@@ -376,9 +381,10 @@ def lm_forward(
     x, new_caches, aux = run_stages(params["stages"], cfg.stages, x, cfg,
                                     positions=positions, mode=mode, caches=caches,
                                     cross_src=cross_src, block_tables=block_tables)
-    x = norm_apply(params["final_norm"], x, cfg)
-    logits = unembed(params["embed"], x, cfg)
-    logits = shard_l(logits, ("batch", "seq", "act_vocab"))
+    with jax.named_scope("head"):
+        x = norm_apply(params["final_norm"], x, cfg)
+        logits = unembed(params["embed"], x, cfg)
+        logits = shard_l(logits, ("batch", "seq", "act_vocab"))
     out = {"logits": logits, "aux": aux, "caches": new_caches, "enc_out": enc_out}
 
     if cfg.mtp_depth and mode == "train":

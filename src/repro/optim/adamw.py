@@ -63,31 +63,32 @@ def _clip_by_global_norm(grads, max_norm: float):
 def adamw_update(
     params, grads, opt_state, tc: TrainConfig
 ) -> Tuple[Any, Any, Dict[str, jax.Array]]:
-    grads, gnorm = _clip_by_global_norm(grads, tc.grad_clip)
-    count = opt_state["count"] + 1
-    cf = count.astype(jnp.float32)
-    b1, b2 = tc.b1, tc.b2
-    lr = lr_at(count, tc)
-    bc1 = 1 - b1 ** cf
-    bc2 = 1 - b2 ** cf
+    with jax.named_scope("optimizer"):
+        grads, gnorm = _clip_by_global_norm(grads, tc.grad_clip)
+        count = opt_state["count"] + 1
+        cf = count.astype(jnp.float32)
+        b1, b2 = tc.b1, tc.b2
+        lr = lr_at(count, tc)
+        bc1 = 1 - b1 ** cf
+        bc2 = 1 - b2 ** cf
 
-    def upd(p, g, m, v):
-        gf = g.astype(jnp.float32)
-        mf = m.astype(jnp.float32) * b1 + gf * (1 - b1)
-        vf = v.astype(jnp.float32) * b2 + jnp.square(gf) * (1 - b2)
-        step = (mf / bc1) / (jnp.sqrt(vf / bc2) + tc.eps)
-        if p.ndim >= 2 and tc.weight_decay:
-            step = step + tc.weight_decay * p.astype(jnp.float32)
-        newp = p.astype(jnp.float32) - lr * step
-        return newp.astype(p.dtype), mf.astype(m.dtype), vf.astype(v.dtype)
+        def upd(p, g, m, v):
+            gf = g.astype(jnp.float32)
+            mf = m.astype(jnp.float32) * b1 + gf * (1 - b1)
+            vf = v.astype(jnp.float32) * b2 + jnp.square(gf) * (1 - b2)
+            step = (mf / bc1) / (jnp.sqrt(vf / bc2) + tc.eps)
+            if p.ndim >= 2 and tc.weight_decay:
+                step = step + tc.weight_decay * p.astype(jnp.float32)
+            newp = p.astype(jnp.float32) - lr * step
+            return newp.astype(p.dtype), mf.astype(m.dtype), vf.astype(v.dtype)
 
-    flat_p, td = jax.tree.flatten(params)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(opt_state["m"])
-    flat_v = jax.tree.leaves(opt_state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree.unflatten(td, [o[0] for o in out])
-    new_m = jax.tree.unflatten(td, [o[1] for o in out])
-    new_v = jax.tree.unflatten(td, [o[2] for o in out])
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_p, {"m": new_m, "v": new_v, "count": count}, metrics
+        flat_p, td = jax.tree.flatten(params)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(opt_state["m"])
+        flat_v = jax.tree.leaves(opt_state["v"])
+        out = [upd(p, g, m, v) for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree.unflatten(td, [o[0] for o in out])
+        new_m = jax.tree.unflatten(td, [o[1] for o in out])
+        new_v = jax.tree.unflatten(td, [o[2] for o in out])
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_p, {"m": new_m, "v": new_v, "count": count}, metrics
