@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Smoke run of the main path on a TPU: the four Pallas kernels, V-cycle
+"""Smoke run of the main path on a TPU: the five Pallas kernels, V-cycle
 training and paged serving, at GPT-Base width, in one process.
 
     python3 chip_smoke.py              # one chip
@@ -17,7 +17,8 @@ One chip runs three phases:
    bit-identically;
 3. serving -- ``make_server(engine="paged")`` with the trained weights
    (``set_params``) answers 8 requests of 16-512 prompt tokens, each with
-   exactly ``max_new`` tokens that agree with a full forward pass.
+   exactly ``max_new`` tokens that agree with a full forward pass; the
+   16-token prompts' cold prefill runs ``attention_tile``.
 
 ``--chips 4`` runs only what exists across chips: the phase-2 V-cycle on a
 (2, 2) ("data", "model") mesh against the same steps unsharded, and paged
@@ -153,11 +154,29 @@ def kernel_phase(cfg, sz: Sizes, seed: int) -> None:
                           jnp.int32)
     qkv = [normal((sz.batch, H, sz.seq_len, D), jnp.bfloat16) for _ in range(3)]
     ct = normal((sz.batch, H, sz.seq_len, D), jnp.bfloat16)
+    # attention_tile (inputs of their own key): a 16-token cold prefill, and
+    # short training rows
+    tk = iter(jax.random.split(jax.random.fold_in(jax.random.PRNGKey(seed), 1), 7))
+    rows = lambda b, s: jax.random.normal(next(tk), (b, s, H * D), jnp.bfloat16)
+    prompt = min(sz.prompt_lens)
+    tile_fwd = [rows(1, prompt) for _ in range(3)]
+    tile_fwd.append(jnp.arange(prompt, dtype=jnp.int32)[None])
+    tile_rows = [rows(sz.batch, 128) for _ in range(3)]
+    tile_rows.append(jnp.broadcast_to(jnp.arange(128, dtype=jnp.int32),
+                                      (sz.batch, 128)))
+    tile_ct = rows(sz.batch, 128)
+    tile_kw = dict(head_dim=D, causal=True, scale=D ** -0.5, theta=cfg.rope_theta)
 
     def flash_grads(impl):
         def loss(q, k, v):
             o = impl(q, k, v, causal=True)
             return jnp.sum(o.astype(jnp.float32) * ct.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))
+
+    def tile_grads(impl):
+        def loss(q, k, v, positions):
+            o = impl(q, k, v, positions, **tile_kw)
+            return jnp.sum(o.astype(jnp.float32) * tile_ct.astype(jnp.float32))
         return jax.grad(loss, argnums=(0, 1, 2))
 
     # (op, label, build(impl) -> fn, args, relative tolerance, kernels >= n)
@@ -172,6 +191,10 @@ def kernel_phase(cfg, sz: Sizes, seed: int) -> None:
          lambda impl: functools.partial(impl, causal=True), qkv, 2e-2, 1),
         ("flash_attention", "flash_attention vjp dq,dk,dv bf16 causal",
          flash_grads, qkv, 2e-2, 3),
+        ("attention_tile", f"attention_tile fwd [1,{prompt},H*D] bf16 causal",
+         lambda impl: functools.partial(impl, **tile_kw), tile_fwd, 2e-2, 1),
+        ("attention_tile", "attention_tile vjp dq,dk,dv [B,128,H*D] bf16 causal",
+         tile_grads, tile_rows, 2e-2, 2),
         ("paged_attention_decode", "paged_attention_decode [B,KH,P,D] bf16",
          lambda impl: impl,
          (normal((sz.serve_batch, H, 1, D), jnp.bfloat16),
@@ -436,7 +459,7 @@ def main(argv=None) -> int:
         off = sorted({f"{op}={b}" for (op, b) in ran if b != "pallas"})
         check(not off, f"ops left the Pallas kernels: {off}")
         want = {"coalesce_pair", "interp_axpy", "flash_attention",
-                "paged_attention_decode"}
+                "paged_attention_decode", "attention_tile"}
         check(want <= {op for op, _ in ran},
               f"ops never traced: {sorted(want - {op for op, _ in ran})}")
     except SmokeFailure as e:

@@ -1,7 +1,8 @@
 """Kernel backend registry + dispatch layer.
 
 Every compute hot-spot the paper optimizes (``flash_attention``,
-``coalesce_pair``, ``interp_axpy``) plus the serving-side
+``coalesce_pair``, ``interp_axpy``), the short-sequence ``attention_tile``
+(rotary + attention in one tile) and the serving-side
 ``paged_attention_decode`` (block-table KV gather) is registered under three
 backends:
 
@@ -39,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh, current_rules, logical_spec
+from repro.kernels import attention_tile as tile_lib
 from repro.kernels import ref
 from repro.kernels.coalesce_pair import coalesce_pair
 from repro.kernels.flash_attention import flash_attention_with_vjp
@@ -181,6 +183,23 @@ def _flash_specs(mesh, rules, q, k, v, **_):
     return (spec, spec, spec), spec
 
 
+def _tile_specs(mesh, rules, q, k, v, positions, *, head_dim, **_):
+    """Split rows over the batch axes and the merged ``H*D`` lanes on head
+    boundaries, where each shard's heads still fit the kernel's lanes."""
+    B, S, HD = q.shape
+    heads = HD // head_dim
+    spec = logical_spec((B, S, heads, head_dim),
+                        ("batch", "seq", "act_heads", "head_dim"), mesh, rules)
+    split = spec[2]
+    n = 1
+    for a in (split if isinstance(split, tuple) else (split,) if split else ()):
+        n *= mesh.shape[a]
+    if not tile_lib.fits(S, heads // n, head_dim):
+        split = None
+    rows = P(spec[0], None, split)
+    return (rows, rows, rows, P(spec[0], None)), rows
+
+
 def _coalesce_specs(mesh, rules, w, *, axis, **_):
     other = list(w.shape)
     other[axis] = 1  # the paired dim stays whole on every shard
@@ -219,6 +238,35 @@ def _flash_attention_interpret(q, k, v, *, causal=True, scale=None,
 def _flash_attention_xla(q, k, v, *, causal=True, scale=None,
                          block_q=0, block_k=0):
     return ref.naive_attention(q, k, v, causal=causal, scale=scale)
+
+
+@_per_shard(_tile_specs)
+def _attention_tile_pallas(q, k, v, positions, *, head_dim, causal, scale,
+                           theta, interpret=False):
+    return tile_lib.attention_tile(q, k, v, positions, head_dim=head_dim,
+                                   causal=causal, scale=scale, theta=theta,
+                                   interpret=interpret)
+
+
+def _attention_tile_interpret(q, k, v, positions, *, head_dim, causal, scale,
+                              theta):
+    return _attention_tile_pallas(q, k, v, positions, head_dim=head_dim,
+                                  causal=causal, scale=scale, theta=theta,
+                                  interpret=True)
+
+
+def _attention_tile_xla(q, k, v, positions, *, head_dim, causal, scale, theta):
+    """The reference: ``apply_rope`` + ``plain_attention`` on [B,S,H,D]."""
+    from repro.layers.attention import plain_attention  # imports this module
+    from repro.layers.basic import apply_rope
+
+    B, S, HD = q.shape
+    heads = (B, S, HD // head_dim, head_dim)
+    qr = apply_rope(q.reshape(heads), positions, theta)
+    kr = apply_rope(k.reshape(heads), positions, theta)
+    out = plain_attention(qr[:, :, :, None], kr, v.reshape(heads),
+                          causal=causal, scale=scale)
+    return out.reshape(B, S, HD)
 
 
 def coalesce_pair_xla(w, *, axis: int, w0: float = 0.5, block: int = 0):
@@ -278,6 +326,10 @@ def _paged_attention_xla(q, k_pages, v_pages, block_tables, lengths, *,
 register("flash_attention", "pallas", _flash_attention_pallas)
 register("flash_attention", "pallas-interpret", _flash_attention_interpret)
 register("flash_attention", "xla", _flash_attention_xla)
+
+register("attention_tile", "pallas", _attention_tile_pallas)
+register("attention_tile", "pallas-interpret", _attention_tile_interpret)
+register("attention_tile", "xla", _attention_tile_xla)
 
 register("coalesce_pair", "pallas", _coalesce_pair_pallas)
 register("coalesce_pair", "pallas-interpret", _coalesce_pair_interpret)

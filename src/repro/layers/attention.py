@@ -15,6 +15,11 @@ are padded.  The XLA flash recipe below remains for the shapes the kernel
 cannot express (causal S != T, padded non-causal keys).  Both flash paths
 assume query positions 0..S-1 (train/prefill); decode uses plain attention.
 
+Short self-attention without a cache (``S <= 128``, rotary positions) takes
+``attention_tile`` instead -- the rotary, scores, softmax and their backward
+in one Pallas kernel pair -- whenever its backend resolves to a Pallas kernel
+(``_tile_backend``).
+
 All attention math runs in fp32 softmax with bf16 matmul inputs (TPU MXU style).
 """
 from __future__ import annotations
@@ -27,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.distributed import shard_l
+from repro.kernels import attention_tile as tile_lib
 from repro.kernels import dispatch as kdispatch
 from repro.kernels.flash_attention import flash_tiles
 from repro.layers.basic import apply_rope, rms_norm
@@ -370,6 +376,59 @@ def run_attention(q, k, v, cfg: ModelConfig, *, causal: bool, scale: float,
     return plain_attention(q, k, v, causal=causal, scale=scale, q_positions=q_positions)
 
 
+def _tile_backend(cfg: ModelConfig, S: int, *, use_rope: bool,
+                  cache: Optional[Dict]) -> Optional[str]:
+    """The Pallas backend of ``attention_tile`` when this self-attention call
+    takes it, else None.  It does when the call can be seen to fit one tile:
+    no cache (training, eval and a cold prefill: the keys are the queries'
+    own S tokens, at positions from 0), rotary positions, ``S <= 128`` and
+    a multiple of 8, heads whose width divides 128 lanes and that fill whole
+    128-lane tiles, and a backend that resolves to a Pallas kernel (on TPU unless the config
+    or the environment pins ``xla``; off TPU only when pinned to
+    ``pallas``/``pallas-interpret``)."""
+    if (cache is not None or not use_rope
+            or not tile_lib.fits(S, cfg.n_heads, cfg.resolved_head_dim)):
+        return None
+    backend = kdispatch.resolve_backend("attention_tile",
+                                        cfg.kernel_backend or None)
+    return None if backend == "xla" else backend
+
+
+def _gqa_tile(p: Dict, x: jax.Array, cfg: ModelConfig, *, positions: jax.Array,
+              causal: bool, backend: str) -> jax.Array:
+    """Self-attention of one short sequence through ``attention_tile``.
+
+    The projections write the heads side by side, ``[B,S,H*D]``, which is
+    the kernel's layout, so nothing is transposed around it; the rotary runs
+    inside the kernel.  (Projecting to ``[B,S,H,D]`` as ``gqa_apply`` does
+    and reshaping puts layout copies around the kernels and float32
+    ``[B,S,H,D]`` arrays back in the compiled v5e train step.)  GQA K/V
+    are repeated over the query groups first, so the group-sum of dk/dv
+    falls out of the repeat's own VJP."""
+    B, S, E = x.shape
+    H, KH, D = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    cdt = cfg.compute_dtype
+
+    def proj(name: str, n: int, norm: Optional[str]) -> jax.Array:
+        y = jnp.einsum("bse,ef->bsf", x, p["w" + name].astype(cdt).reshape(E, n * D))
+        if cfg.use_bias:
+            y = y + p["b" + name].astype(cdt).reshape(n * D)
+        if norm and cfg.qk_norm:
+            y = rms_norm(y.reshape(B, S, n, D), p[norm], cfg.norm_eps)
+        if n != H:
+            y = jnp.repeat(y.reshape(B, S, n, D), H // n, axis=2)
+        return y.reshape(B, S, H * D)
+
+    out = kdispatch.get_impl("attention_tile", backend)(
+        proj("q", H, "q_norm"), proj("k", KH, "k_norm"), proj("v", KH, None),
+        positions, head_dim=D, causal=causal, scale=D ** -0.5,
+        theta=cfg.rope_theta)
+    y = jnp.einsum("bsf,fe->bse", out, p["wo"].astype(cdt).reshape(H * D, E))
+    if cfg.use_bias:
+        y = y + p["bo"].astype(cdt)
+    return shard_l(y, ("batch", "seq", "act_embed"))
+
+
 # ---------------------------------------------------------------------------
 # GQA layer
 
@@ -456,6 +515,10 @@ def gqa_apply(
     block_tables: Optional[jax.Array] = None,  # [B,M]: cache is paged
 ) -> Tuple[jax.Array, Optional[Dict]]:
     B, S, E = x.shape
+    tile = _tile_backend(cfg, S, use_rope=use_rope, cache=cache)
+    if tile is not None:
+        return _gqa_tile(p, x, cfg, positions=positions, causal=causal,
+                         backend=tile), None
     H, KH, D = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     cdt = cfg.compute_dtype
     q = jnp.einsum("bse,ehd->bshd", x, p["wq"].astype(cdt))

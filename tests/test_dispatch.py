@@ -27,7 +27,8 @@ ML = MultiLevelConfig(n_levels=2)
 
 
 def test_registry_contents():
-    assert dispatch.ops() == ("coalesce_pair", "flash_attention", "interp_axpy",
+    assert dispatch.ops() == ("attention_tile", "coalesce_pair",
+                              "flash_attention", "interp_axpy",
                               "paged_attention_decode")
     for op in dispatch.ops():
         assert dispatch.backends(op) == dispatch.BACKENDS

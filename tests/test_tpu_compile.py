@@ -107,3 +107,72 @@ def test_paged_attention_decode_compiles(one_chip):
         ((batch, M), jnp.int32),               # block tables
         ((batch,), jnp.int32))                 # lengths
     _assert_kernel(text)
+
+
+# BERT-Large training (benchmarks/chip/configs/bert-large.json): 64 x 128
+# tokens, 16 heads of 64 at level 0, 8 at level 1 of the V-cycle
+@pytest.mark.parametrize("heads", [16, 8], ids=["level0", "level1"])
+@pytest.mark.parametrize("causal", [False, True], ids=["bidir", "causal"])
+def test_attention_tile_fwd_and_vjp_compile(one_chip, heads, causal):
+    fn = dispatch.get_impl("attention_tile", "pallas")
+    rows = ((64, 128, heads * 64), jnp.bfloat16)
+
+    def loss(q, k, v, positions):
+        out = fn(q, k, v, positions, head_dim=64, causal=causal, scale=0.125,
+                 theta=10000.0)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                         rows, rows, rows, ((64, 128), jnp.int32))
+    assert "attention_tile_fwd" in text and "attention_tile_bwd" in text
+    _assert_kernel(text, n=2)
+
+
+# a cold serving prefill of GPT-Base: one prompt of 16 tokens, 12 heads of 64;
+# the float32 case is the compute type of chip_smoke's 1x4 decode check
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_attention_tile_prefill_compiles(one_chip, dtype):
+    fn = dispatch.get_impl("attention_tile", "pallas")
+    rows = ((1, 16, H * D), dtype)
+    text = _compile_text(
+        lambda q, k, v, pos: fn(q, k, v, pos, head_dim=D, causal=True,
+                                scale=D ** -0.5, theta=10000.0),
+        one_chip, rows, rows, rows, ((1, 16), jnp.int32))
+    assert "attention_tile_fwd" in text
+    _assert_kernel(text)
+
+
+def test_attention_tile_train_step_layout(one_chip, monkeypatch):
+    """In a whole train step the kernels read the projections' own output
+    and nothing float32 of the attention's [B, S, H, D] shape is left: no
+    transpose or copy sits between the projections and the kernels."""
+    import re
+
+    from repro.configs.paper_models import bert_proxy
+    from repro.models.api import build_model
+
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cfg = bert_proxy(d_model=256, n_layers=2, vocab=512).replace(
+        remat="full", compute_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def step(params, tokens, labels):
+        return jax.grad(lambda p: model.loss(p, {"tokens": tokens,
+                                                 "labels": labels})[0])(params)
+
+    shard = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), t)
+    toks = jax.ShapeDtypeStruct((8, 128), jnp.int32, sharding=one_chip)
+    text = jax.jit(step).lower(shard(params), toks, toks).compile().as_text()
+    calls = [l for l in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in l
+             and re.search(r"attention_tile_(fwd|bwd)", l)]
+    assert len(calls) >= 3  # forward, recomputed forward, backward
+    assert "f32[8,128,4,64]" not in text
+    for line in calls:
+        # operands: the rotary table (cos, sin), then the [B, S, H*D] rows
+        operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        rows = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")[2:]
+        # (a copy-start/-done pair moves a buffer between memory spaces)
+        assert not [r for r in rows if re.fullmatch(r"%(copy|transpose)(\.\d+)?", r)], line
