@@ -434,8 +434,8 @@ def main(argv=None) -> int:
     from repro.kernels import dispatch
 
     sz = Sizes()
-    # attn_impl="pallas" puts the flash kernels in the training step too
-    cfg = get_config(sz.arch).replace(attn_impl="pallas")
+    # at 1024 tokens the shapes put the flash kernels in the training step
+    cfg = get_config(sz.arch)
     log(f"config {cfg.name}: d_model {cfg.d_model}, {cfg.n_heads} heads, "
         f"{cfg.n_layers} layers, vocab {cfg.vocab_size} (padded "
         f"{cfg.padded_vocab}), attn_impl {cfg.attn_impl}; batch {sz.batch} x "

@@ -111,7 +111,11 @@ class ModelConfig:
     ssm_chunk: int = 128  # recurrent-scan remat chunk (memory / (S/chunk))
     attn_seq_shard: bool = False  # shard attn activations along seq (context
     # parallelism) when the head count does not divide the model axis
-    attn_impl: str = "blockwise"  # plain | blockwise | pallas
+    # plain | blockwise | pallas | pairs.  Past 128 tokens, blockwise and
+    # pallas let the shapes decide: on a TPU the Pallas flash kernels where
+    # they can express the call, else the XLA flash recipe; off TPU
+    # "pallas" prefers the interpreted kernels (layers/attention.py)
+    attn_impl: str = "blockwise"
     attn_block_k: int = 512
     kernel_backend: str = ""  # "" = auto; else pallas | pallas-interpret | xla
     # (per-op resolution lives in repro.kernels.dispatch; REPRO_KERNEL_BACKEND

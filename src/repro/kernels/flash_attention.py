@@ -22,6 +22,9 @@ kernels -- one accumulating dq over k-blocks, one accumulating (dk, dv) over
 q-blocks -- so no O(S^2) intermediate ever touches HBM.
 ``flash_attention_with_vjp`` packages fwd+bwd behind ``jax.custom_vjp``.
 
+The three calls are named ``flash_attention_fwd``, ``flash_attention_dq``
+and ``flash_attention_dkv``: the names the kernels carry in a profiler trace.
+
 Validated in interpret mode against ref.naive_attention, values and grads
 (tests/test_kernels.py, tests/test_dispatch.py).
 """
@@ -125,6 +128,7 @@ def _fwd_call(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -292,6 +296,7 @@ def _bwd_call(q, k, v, out, lse, do, *, causal: bool, scale: float, bq: int,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, do, lse, delta)
 
     # (dk, dv) grid transposes the block roles: k-block outer, q-block inner
@@ -316,6 +321,7 @@ def _bwd_call(q, k, v, out, lse, do, *, causal: bool, scale: float, bq: int,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

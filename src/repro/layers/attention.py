@@ -8,12 +8,15 @@ Three core computations:
                              (q-block, k-block) pairs.  Used for long prefill and
                              available for training (perf lever, see EXPERIMENTS).
 
-``attn_impl="pallas"`` additionally dispatches train/prefill attention through
-the kernel registry (repro.kernels.dispatch) to the Pallas flash kernels --
-forward AND backward (custom VJP); sequence lengths the blocks do not divide
-are padded.  The XLA flash recipe below remains for the shapes the kernel
-cannot express (causal S != T, padded non-causal keys).  Both flash paths
-assume query positions 0..S-1 (train/prefill); decode uses plain attention.
+Train/prefill attention past 128 tokens takes the Pallas flash kernels
+(repro.kernels.dispatch, forward AND backward through a custom VJP) when the
+shapes allow and ``flash_attention`` resolves to a Pallas backend: on TPU
+unless ``xla`` is pinned (``_flash_backend``), in tiles of the whole sequence
+up to ``FLASH_TILE`` tokens; sequence lengths the blocks do not divide are
+padded.  The XLA flash recipe below remains for the shapes the
+kernel cannot express (causal S != T, padded non-causal keys) and off TPU.
+Both flash paths assume query positions 0..S-1 (train/prefill); decode uses
+plain attention.
 
 Short self-attention without a cache (``S <= 128``, rotary positions) takes
 ``attention_tile`` instead -- the rotary, scores, softmax and their backward
@@ -344,8 +347,25 @@ def _flash_pallas(q, k, v, *, causal: bool, scale: float, bq: int, bk: int,
     return out.reshape(B, KH, G, S, Dv).transpose(0, 3, 1, 2, 4)
 
 
+# The flash kernels' q and k tile: the whole sequence up to 1024 tokens.
+# On a TPU v5e at GPT-Base's 1024 tokens (12 and 6 heads of 64), forward
+# plus backward took 5.2 / 3.1 ms in 1024 x 1024 tiles, 6.1 / 3.5 in 512 x
+# 512 and 18.9 / 9.6 in 128 x 128, against flash_xla's 11.4 / 5.4: a grid
+# step costs more than the masked tiles a larger one cannot skip (PERF.md).
+FLASH_TILE = 1024
+
+
 def run_attention(q, k, v, cfg: ModelConfig, *, causal: bool, scale: float,
                   q_positions=None, decode: bool = False) -> jax.Array:
+    """Attention of q ``[B,S,KH,G,D]`` over k, v ``[B,T,KH,D]``.
+
+    Decode and short calls (``S <= 128`` or ``T <= attn_block_k``) keep
+    ``plain_attention``; ``attn_impl="pairs"`` takes the causal pairs scan
+    where it divides; ``attn_impl="plain"`` keeps ``plain_attention``.
+    Otherwise the shapes decide (``_flash_backend``): a call the Pallas
+    flash kernels can express runs them wherever ``flash_attention``
+    resolves to a Pallas backend, and every other call takes ``flash_xla``,
+    the same recipe at the XLA level."""
     S, T = q.shape[1], k.shape[1]
     impl = cfg.attn_impl
     if decode or S <= 128 or T <= cfg.attn_block_k:
@@ -354,26 +374,36 @@ def run_attention(q, k, v, cfg: ModelConfig, *, causal: bool, scale: float,
         # FLOP-exact causal (lower-triangular block pairs); best for no-grad
         # prefill where the rectangular fwd would waste ~2x attention FLOPs.
         return pairs_attention(q, k, v, scale=scale, block=cfg.attn_block_k)
-    if impl == "pallas":
-        # genuine Pallas dispatch (fwd + custom-VJP bwd kernels): Mosaic on
-        # TPU, the interpreter off-TPU unless the config/env pins "xla".
-        backend = kdispatch.resolve_backend(
-            "flash_attention", cfg.kernel_backend or None, default="pallas")
-        bk = min(cfg.attn_block_k, 128)
-        expressible = (S == T if causal else
-                       flash_tiles(S, T, 128, bk, causal, q.dtype) is not None)
-        if backend != "xla" and expressible:
-            return _flash_pallas(q, k, v, causal=causal, scale=scale,
-                                 bq=128, bk=bk, backend=backend)
-        # fall through to the XLA flash recipe below (the same algorithm):
-        # the kernel assumes causal queries at 0..S-1 over keys 0..S-1 and
-        # has no key mask for padded non-causal keys.  Noted, so a run can
-        # tell which implementation it traced.
-        kdispatch.note("flash_attention", "xla")
-    if impl in ("blockwise", "pallas", "pairs"):
-        # memory-optimal custom-VJP path (flash recipe at the XLA level)
-        return flash_xla(q, k, v, causal, scale, cfg.attn_block_k)
-    return plain_attention(q, k, v, causal=causal, scale=scale, q_positions=q_positions)
+    if impl not in ("blockwise", "pallas", "pairs"):
+        return plain_attention(q, k, v, causal=causal, scale=scale, q_positions=q_positions)
+    backend = _flash_backend(cfg, S, T, causal=causal, dtype=q.dtype)
+    if backend is not None:
+        return _flash_pallas(q, k, v, causal=causal, scale=scale, bq=FLASH_TILE,
+                             bk=FLASH_TILE, backend=backend)
+    # the XLA flash recipe (the same algorithm), noted so that a run can tell
+    # which implementation it traced
+    kdispatch.note("flash_attention", "xla")
+    return flash_xla(q, k, v, causal, scale, cfg.attn_block_k)
+
+
+def _flash_backend(cfg: ModelConfig, S: int, T: int, *, causal: bool,
+                   dtype) -> Optional[str]:
+    """The Pallas backend of ``flash_attention`` when this call takes the
+    kernels, else None.  It does when the kernels can express the call --
+    causal with ``S == T`` (queries and keys both at positions 0..S-1), or
+    non-causal keys that the blocks tile without a pad -- and the backend
+    resolves to a Pallas kernel: on TPU unless the config or the
+    environment pins ``xla``; off TPU only when pinned to
+    ``pallas``/``pallas-interpret``, or under ``attn_impl="pallas"``, which
+    still prefers the kernels (the interpreter there)."""
+    expressible = (S == T if causal else
+                   flash_tiles(S, T, FLASH_TILE, FLASH_TILE, causal, dtype) is not None)
+    if not expressible:
+        return None
+    backend = kdispatch.resolve_backend(
+        "flash_attention", cfg.kernel_backend or None,
+        default="pallas" if cfg.attn_impl == "pallas" else None)
+    return None if backend == "xla" else backend
 
 
 def _tile_backend(cfg: ModelConfig, S: int, *, use_rope: bool,

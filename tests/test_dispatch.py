@@ -1,6 +1,6 @@
 """Kernel dispatch subsystem: registry resolution, Pallas flash attention
 forward AND backward parity (interpret mode), end-to-end ``attn_impl="pallas"``
-execution, per-shard kernels under a mesh, and fused-vs-matrix equivalence of
+execution, the shape rule that picks the flash kernels on a TPU, per-shard kernels under a mesh, and fused-vs-matrix equivalence of
 the level-transition operators on a full parameter tree."""
 import os
 import subprocess
@@ -191,6 +191,72 @@ def test_run_attention_xla_backend_override():
     finally:
         dispatch.register("flash_attention", "pallas-interpret", orig, override=True)
     assert not calls
+
+
+# ---------------------------------------------------------------------------
+# the shape rule: on a TPU the shapes, not attn_impl, pick the flash kernels
+
+
+@pytest.fixture
+def tpu_flash_calls(monkeypatch):
+    """A faked TPU whose Mosaic flash kernel records its calls (and runs
+    the interpreter in its place)."""
+    calls = []
+    interp = dispatch.get_impl("flash_attention", "pallas-interpret")
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    monkeypatch.setitem(dispatch._REGISTRY["flash_attention"], "pallas", lambda *a, **kw: (
+        calls.append((kw["block_q"], kw["block_k"])), interp(*a, **kw))[1])
+    dispatch.reset_traced()
+    return calls
+
+
+def _attend(cfg, S=256, T=None, causal=True, decode=False):
+    q, k, v, _ = _qkv(S=S)
+    if T is not None:
+        k, v = _qkv(S=T, key=1)[1:3]
+    return jax.eval_shape(lambda q, k, v: attn.run_attention(
+        q, k, v, cfg, causal=causal, scale=q.shape[-1] ** -0.5, decode=decode), q, k, v)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "bidir"])
+def test_flash_rule_takes_the_kernels_on_tpu(tpu_flash_calls, causal):
+    """The default attn_impl ("blockwise") past 128 tokens runs the Pallas
+    kernels on a TPU."""
+    out = _attend(tiny_dense(attn_impl="blockwise", attn_block_k=64), causal=causal)
+    assert out.shape == (1, 256, 2, 2, 16)
+    assert tpu_flash_calls == [(attn.FLASH_TILE, attn.FLASH_TILE)]
+    assert dispatch.traced() == {("flash_attention", "pallas"): 1}
+
+
+# what each call traces instead: flash_xla is noted, plain attention is not
+@pytest.mark.parametrize("case,xla", [
+    (dict(cfg=dict(kernel_backend="xla")), True), (dict(env="xla"), True),
+    (dict(S=192, T=256), True), (dict(S=128), False), (dict(decode=True), False),
+    (dict(cfg=dict(attn_block_k=512)), False), (dict(cfg=dict(attn_impl="plain")), False),
+], ids=["pinned_xla", "env_xla", "causal_S_ne_T", "S128", "decode", "T_le_block", "plain"])
+def test_flash_rule_keeps_the_other_paths(tpu_flash_calls, monkeypatch, case, xla):
+    if "env" in case:
+        monkeypatch.setenv(dispatch.ENV_VAR, case["env"])
+    cfg = tiny_dense(**dict(dict(attn_impl="blockwise", attn_block_k=64), **case.get("cfg", {})))
+    _attend(cfg, S=case.get("S", 256), T=case.get("T"), decode=case.get("decode", False))
+    assert not tpu_flash_calls
+    assert dispatch.traced() == ({("flash_attention", "xla"): 1} if xla else {})
+
+
+def test_flash_rule_off_tpu_is_unchanged(monkeypatch):
+    """Off a TPU, with nothing pinned, the default path stays flash_xla;
+    attn_impl="pallas" still prefers the interpreted kernels."""
+    if dispatch.on_tpu():
+        pytest.skip("off-TPU behaviour")
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    cfg = tiny_dense(attn_impl="blockwise", attn_block_k=64)
+    dispatch.reset_traced()
+    _attend(cfg)
+    assert dispatch.traced() == {("flash_attention", "xla"): 1}
+    dispatch.reset_traced()
+    _attend(cfg.replace(attn_impl="pallas"))
+    assert dispatch.traced() == {("flash_attention", "pallas-interpret"): 1}
 
 
 # ---------------------------------------------------------------------------

@@ -176,3 +176,41 @@ def test_attention_tile_train_step_layout(one_chip, monkeypatch):
         rows = re.sub(r"/\*index=\d+\*/", "", operands).split(", ")[2:]
         # (a copy-start/-done pair moves a buffer between memory spaces)
         assert not [r for r in rows if re.fullmatch(r"%(copy|transpose)(\.\d+)?", r)], line
+
+
+def _gpt_base_step_text(one_chip, **replace):
+    """The compiled level-0 V-cycle train step of GPT-Base at 2 x 1024."""
+    from repro.config import MultiLevelConfig, TrainConfig
+    from repro.configs import get_config
+    from repro.core.vcycle import VCycleRunner
+    from repro.optim import adamw_init
+
+    cfg = get_config("gpt-base").replace(**replace)
+    tc = TrainConfig(batch_size=2, seq_len=S)
+    runner = VCycleRunner(cfg, MultiLevelConfig(n_levels=2, alpha=0.5), tc,
+                          batch_fn=lambda g: None, seed=0)
+    params = jax.eval_shape(runner.models[0].init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(lambda p: adamw_init(p, tc=tc), params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, S), jnp.int32),
+             "labels": jax.ShapeDtypeStruct((2, S), jnp.int32)}
+    shard = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), t)
+    step = runner.step_fn(0).__wrapped__
+    return step.lower(shard(params), shard(opt), shard(batch)).compile().as_text()
+
+
+def test_gpt_base_train_step_takes_the_flash_kernels(one_chip, monkeypatch):
+    """On a TPU the shapes alone put GPT-Base's 1024-token attention through
+    the three named Pallas kernels, with no XLA flash scan left in the
+    attention scope; pinning ``xla`` keeps that scan."""
+    import re
+
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    scan = re.compile(r'op_name="[^"]*/attention/while')
+    text = _gpt_base_step_text(one_chip)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
+        assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text), kernel
+    assert not scan.search(text)
+    pinned = _gpt_base_step_text(one_chip, kernel_backend="xla")
+    assert scan.search(pinned) and "flash_attention_fwd" not in pinned
