@@ -8,9 +8,21 @@ path only gets from the pairs-scan).
 
 Grid: (batch*heads, n_q_blocks, n_k_blocks); the k-block axis is innermost so
 scratch accumulators persist per (bh, qi) like the reference TPU kernel.
+A causal tile on the diagonal (square tiles larger than ``DIAGONAL_BLOCK``)
+is walked in static sub-blocks of ``diagonal_block(bq)`` rows, so the masked
+half of its square is never computed: in the forward and ``dq`` query
+sub-block ``i`` meets only keys ``[0, (i+1)c)`` of the tile, in ``dk/dv`` key
+sub-block ``j`` meets only queries ``[jc, bq)``, and only the ``c x c`` block
+on the diagonal holds masked scores.  At 1024 tokens in one 1024-tile that is
+10 of its 16 blocks of 256 (``causal_share``).  Tiles below the diagonal, and
+every tile of a non-causal call or of tiles of 256 rows or fewer, are
+computed whole.
 Per-row statistics (running max, denominator, log-sum-exp, the backward's
 delta) are kept replicated across ``STAT_LANES`` lanes, so every block's last
-two dims are Mosaic-legal: ``(bq, 128)`` rather than a 1-D ``(bq,)`` row.
+two dims are Mosaic-legal: ``(bq, 128)`` rather than a 1-D ``(bq,)`` row; they
+meet the scores by whole lane tiles (``_lanes``), not by a broadcast of one
+lane, which would keep the cross-lane unit busy for as long as the matrix
+units save in a walked tile.
 Masked scores are the finite ``NEG_INF``: the first k-block of every row holds
 key 0, which causality always admits, so the running max is finite from the
 first computed tile and masked probabilities come out exactly 0.
@@ -43,6 +55,45 @@ from repro.kernels.tiling import round_up, sublane, tile
 
 NEG_INF = -1e30
 STAT_LANES = 128  # per-row statistics, replicated across one lane tile
+DIAGONAL_BLOCK = 256  # rows of a walked diagonal tile's sub-blocks
+
+
+def diagonal_block(bq: int) -> int:
+    """Rows of the sub-blocks a causal diagonal tile of ``bq`` rows is walked
+    in: ``DIAGONAL_BLOCK`` where it divides a larger tile, else the whole tile
+    (which is then computed in one piece, masked)."""
+    if bq > DIAGONAL_BLOCK and bq % DIAGONAL_BLOCK == 0:
+        return DIAGONAL_BLOCK
+    return bq
+
+
+def causal_share(S: int, bq: int, c: int) -> float:
+    """Share of the ``S x S`` score square that a causal call computes with
+    square ``bq`` tiles whose diagonal tiles are walked in ``c``-row
+    sub-blocks: every tile below the diagonal, and the sub-blocks at or below
+    the diagonal of each diagonal tile."""
+    n, m = S // bq, bq // c
+    return (n * (n - 1) // 2 * bq * bq + n * m * (m + 1) // 2 * c * c) / (S * S)
+
+
+def _causal_steps(qi, ki, *, causal: bool, bq: int, bk: int, tile, diagonal):
+    """Run one grid step's part of the score square.
+
+    ``tile()`` computes the whole ``[bq, bk]`` tile (masked when causal);
+    ``diagonal(c)`` walks a diagonal tile in ``c``-row sub-blocks, each
+    against only the rows or columns at or before its own diagonal block.
+    Causal tiles wholly above the diagonal are skipped; a diagonal tile that
+    ``diagonal_block`` splits is walked, so the masked half of its square is
+    never computed."""
+    if not causal:
+        tile()
+        return
+    c = diagonal_block(bq)
+    if bq != bk or c == bq:
+        pl.when(ki * bk <= (qi + 1) * bq - 1)(tile)
+        return
+    pl.when(ki < qi)(tile)
+    pl.when(ki == qi)(functools.partial(diagonal, c))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
@@ -56,40 +107,61 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
-        k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0].astype(jnp.float32)  # [bk, Dv]
+    def _update(rows, cols, mask):
+        """Online-softmax update of query ``rows`` by key ``cols``."""
+        q = q_ref[0, rows].astype(jnp.float32)  # [r, D]
+        k = k_ref[0, cols].astype(jnp.float32)  # [n, D]
+        v = v_ref[0, cols].astype(jnp.float32)  # [n, Dv]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = jnp.where(_causal_mask(qi, ki, bq, bk), s, NEG_INF)
-        m_prev = m_scr[...]  # [bq, STAT_LANES], lanes equal
+        if mask is not None:
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[rows]  # [r, STAT_LANES], lanes equal
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new[:, :1])
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = corr[:, :1] * acc_scr[...] + jax.lax.dot(
+        l_scr[rows] = corr * l_scr[rows] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[rows] = _lanes(corr, acc_scr.shape[1]) * acc_scr[rows] + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[rows] = m_new
 
-    if causal:
-        # skip tiles strictly above the diagonal band
-        pl.when(ki * bk <= (qi + 1) * bq - 1)(_compute)
-    else:
-        _compute()
+    def _tile():
+        _update(pl.ds(0, bq), pl.ds(0, bk),
+                _causal_mask(qi * bq, ki * bk, bq, bk) if causal else None)
+
+    def _diagonal(c):
+        # longest sub-block first: the v5e schedules it 4% shorter
+        for i in reversed(range(bq // c)):
+            # query rows [ic, (i+1)c) against the keys [0, (i+1)c) of the tile
+            _update(pl.ds(i * c, c), pl.ds(0, (i + 1) * c),
+                    _causal_mask(i * c, 0, c, (i + 1) * c))
+
+    _causal_steps(qi, ki, causal=causal, bq=bq, bk=bk, tile=_tile,
+                  diagonal=_diagonal)
 
     @pl.when(ki == nk - 1)
     def _out():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / _lanes(l, acc_scr.shape[1])).astype(o_ref.dtype)
         lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
-def _causal_mask(qi, ki, bq: int, bk: int):
-    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _causal_mask(q0, k0, rows: int, cols: int):
+    """Admitted scores of the ``[rows, cols]`` block whose first query and
+    key sit at positions ``q0`` and ``k0``."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     return kpos <= qpos
+
+
+def _lanes(x: jax.Array, n: int) -> jax.Array:
+    """A lane-replicated ``[r, STAT_LANES]`` statistic as ``[r, n]``, by
+    whole lane tiles where ``n`` allows, so no lane is broadcast."""
+    if n % STAT_LANES == 0:
+        return pltpu.repeat(x, n // STAT_LANES, 1)
+    if n < STAT_LANES:
+        return x[:, :n]
+    return x[:, :1]
 
 
 def _stat_lanes(x: jax.Array) -> jax.Array:
@@ -202,38 +274,44 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        ds = _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-                     scale=scale, causal=causal, bq=bq, bk=bk)[1]
-        dq_scr[...] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
+    def _update(rows, cols, mask):
+        """dq of query ``rows`` from key ``cols``."""
+        k = k_ref[0, cols].astype(jnp.float32)  # [n, D]
+        ds = _bwd_ds(q_ref[0, rows], k, v_ref[0, cols], do_ref[0, rows],
+                     lse_ref[0, rows], delta_ref[0, rows], mask, scale=scale)[1]
+        dq_scr[rows] += jax.lax.dot(ds, k, preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(ki * bk <= (qi + 1) * bq - 1)(_compute)
-    else:
-        _compute()
+    def _tile():
+        _update(pl.ds(0, bq), pl.ds(0, bk),
+                _causal_mask(qi * bq, ki * bk, bq, bk) if causal else None)
+
+    def _diagonal(c):
+        for i in range(bq // c):
+            _update(pl.ds(i * c, c), pl.ds(0, (i + 1) * c),
+                    _causal_mask(i * c, 0, c, (i + 1) * c))
+
+    _causal_steps(qi, ki, causal=causal, bq=bq, bk=bk, tile=_tile,
+                  diagonal=_diagonal)
 
     @pl.when(ki == nk - 1)
     def _out():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
-            scale: float, causal: bool, bq: int, bk: int):
-    """Recomputed probabilities p and score gradient ds of one [bq, bk] tile."""
-    q = q_ref[0].astype(jnp.float32)  # [bq, D]
-    v = v_ref[0].astype(jnp.float32)  # [bk, Dv]
-    do = do_ref[0].astype(jnp.float32)  # [bq, Dv]
-    lse = lse_ref[0][:, :1]  # [bq, 1]
-    delta = delta_ref[0][:, :1]  # [bq, 1]
+def _bwd_ds(q, k, v, do, lse, delta, mask, *, scale: float):
+    """Recomputed probabilities p and score gradient ds of one block of
+    queries ``q`` against keys ``k`` (``k`` already float32)."""
+    q = q.astype(jnp.float32)  # [r, D]
+    v = v.astype(jnp.float32)  # [n, Dv]
+    do = do.astype(jnp.float32)  # [r, Dv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    if causal:
-        s = jnp.where(_causal_mask(qi, ki, bq, bk), s, NEG_INF)
-    p = jnp.exp(s - lse)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - _lanes(lse, s.shape[1]))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [bq, bk]
-    return p, p * (dp - delta) * scale
+                             preferred_element_type=jnp.float32)  # [r, n]
+    return p, p * (dp - _lanes(delta, dp.shape[1])) * scale
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
@@ -247,21 +325,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)  # [bk, D]
-        p, ds = _bwd_ds(q_ref, k, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
-                        scale=scale, causal=causal, bq=bq, bk=bk)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+    def _update(rows, cols, mask):
+        """dk and dv of key ``cols`` from query ``rows``."""
+        k = k_ref[0, cols].astype(jnp.float32)  # [n, D]
+        q = q_ref[0, rows].astype(jnp.float32)  # [r, D]
+        do = do_ref[0, rows].astype(jnp.float32)  # [r, Dv]
+        p, ds = _bwd_ds(q, k, v_ref[0, cols], do, lse_ref[0, rows],
+                        delta_ref[0, rows], mask, scale=scale)
+        dv_scr[cols] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
+        dk_scr[cols] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when((qi + 1) * bq - 1 >= ki * bk)(_compute)
-    else:
-        _compute()
+    def _tile():
+        _update(pl.ds(0, bq), pl.ds(0, bk),
+                _causal_mask(qi * bq, ki * bk, bq, bk) if causal else None)
+
+    def _diagonal(c):
+        # shortest sub-block first: the v5e schedules it 1% shorter
+        for j in reversed(range(bq // c)):
+            # key rows [jc, (j+1)c) against the queries [jc, bq) of the tile
+            _update(pl.ds(j * c, bq - j * c), pl.ds(j * c, c),
+                    _causal_mask(j * c, j * c, bq - j * c, c))
+
+    _causal_steps(qi, ki, causal=causal, bq=bq, bk=bk, tile=_tile,
+                  diagonal=_diagonal)
 
     @pl.when(qi == nq - 1)
     def _out():

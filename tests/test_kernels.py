@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import dispatch, ops, ref
+from repro.kernels import dispatch, flash_attention, ops, ref
 
 # every backend that resolves to itself on this host ("pallas" downgrades to
 # the interpreter off-TPU -- skip the duplicate sweep)
@@ -70,6 +70,87 @@ def test_flash_attention_pads_unaligned_lengths(dtype):
     for a, b in zip(g, w):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), atol=gtol, rtol=gtol)
+
+
+# (S, tile): a diagonal tile walked in 2 and in 4 sub-blocks; a full tile
+# below the diagonal beside two walked ones; a padded length (1500 -> 2048)
+# whose padded keys sit in a walked tile; an unaligned whole tile, computed
+# in one piece
+@pytest.mark.parametrize("S,block", [(512, 512), (1024, 1024), (2048, 1024),
+                                     (1500, 1024), (1000, 1024)],
+                         ids=["S512", "S1024", "S2048", "S1500_padded", "S1000_whole"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_diagonal_walk(S, block, dtype):
+    """Causal tiles larger than ``DIAGONAL_BLOCK`` are walked in sub-blocks:
+    values and q/k/v gradients still match the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, ct = (jax.random.normal(kk, (1, 1, S, 32), dtype) for kk in ks)
+
+    def flash(q, k, v):
+        return ops.flash_attention_vjp(q, k, v, causal=True, block_q=block,
+                                       block_k=block)
+
+    def oracle(q, k, v):
+        return ref.naive_attention(q, k, v, causal=True)
+
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(flash(q, k, v), np.float32),
+                               np.asarray(oracle(q, k, v), np.float32),
+                               atol=tol, rtol=tol)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * ct.astype(jnp.float32))
+
+    g = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    w = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    gtol = 6e-2 if dtype == jnp.bfloat16 else 1e-4
+    for a, b in zip(g, w):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=gtol, rtol=gtol)
+
+
+@pytest.mark.parametrize("bq,c", [(1024, 256), (512, 256), (2048, 256),
+                                  (256, 256), (128, 128), (64, 64), (1000, 1000),
+                                  (640, 640)])
+def test_diagonal_block(bq, c):
+    assert flash_attention.diagonal_block(bq) == c
+
+
+@pytest.mark.parametrize("S,bq,c,share", [
+    (1024, 1024, 256, 0.625),   # one tile, 10 of its 16 sub-blocks
+    (512, 512, 256, 0.75),      # 3 of 4
+    (2048, 1024, 256, 0.5625),  # a full tile beside two walked ones
+    (1000, 1000, 1000, 1.0),    # one whole tile
+    (256, 256, 256, 1.0),
+    (256, 64, 64, 0.625),       # tiles skipped by the grid alone
+])
+def test_causal_share(S, bq, c, share):
+    assert flash_attention.causal_share(S, bq, c) == share
+
+
+@pytest.mark.parametrize("causal,bq,bk", [
+    (True, 1024, 1024), (True, 512, 512), (True, 256, 256), (True, 64, 64),
+    (True, 1024, 512), (False, 1024, 1024), (False, 256, 256)])
+def test_causal_steps_schedule(causal, bq, bk):
+    """Which work each grid step of a 4 x 4 tile grid runs: non-causal calls
+    and causal tiles of 256 rows or fewer (or not square) run the whole tile
+    wherever it holds an admitted score; larger square causal tiles run
+    whole below the diagonal and are walked on it; nothing runs above it."""
+    for qi in range(4):
+        for ki in range(4):
+            ran = []
+            flash_attention._causal_steps(
+                qi, ki, causal=causal, bq=bq, bk=bk,
+                tile=lambda: ran.append("tile"),
+                diagonal=lambda c: ran.append(("walk", c)))
+            if not causal:
+                want = ["tile"]
+            elif bq > 256 and bq == bk:
+                want = ["tile"] if ki < qi else [("walk", 256)] if ki == qi else []
+            else:
+                want = ["tile"] if ki * bk <= (qi + 1) * bq - 1 else []
+            assert ran == want, (qi, ki)
 
 
 @pytest.mark.parametrize("backend", RESOLVABLE)

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import dispatch
+from repro.layers.attention import FLASH_TILE
 
 # GPT-Base (configs/paper_models.py): d_model 768, 12 heads of 64, d_ff 3072,
 # vocab 50257 padded to 50304, 12 layers; training batch 8 x 1024 tokens
@@ -76,22 +77,40 @@ def test_coalesce_pair_compiles(one_chip, shape):
     _assert_kernel(text)
 
 
-@pytest.mark.parametrize("heads", [H, H // 2], ids=["level0", "level1"])
-def test_flash_attention_fwd_and_vjp_compile(one_chip, heads):
+def _assert_flash_compiles(one_chip, heads, *, causal, block):
+    """The forward, and the gradient's forward + dq + dk/dv, each a named
+    Mosaic kernel."""
+    import re
+
     fn = dispatch.get_impl("flash_attention", "pallas")
     qkv = [((B, heads, S, D), jnp.bfloat16)] * 3
 
     def fwd(q, k, v):
-        return fn(q, k, v, causal=True, block_q=128, block_k=128)
-
-    _assert_kernel(_compile_text(fwd, one_chip, *qkv))
+        return fn(q, k, v, causal=causal, block_q=block, block_k=block)
 
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
-    # forward (lse-emitting) + dq kernel + dk/dv kernel
-    _assert_kernel(_compile_text(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-                                 *qkv), n=3)
+    kernels = ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
+    for f, names in ((fwd, kernels[:1]), (jax.grad(loss, argnums=(0, 1, 2)), kernels)):
+        text = _compile_text(f, one_chip, *qkv)
+        for kernel in names:
+            assert re.search(rf"%[\w.]*{kernel}[\w.]* = .*tpu_custom_call", text), kernel
+
+
+# the tiles the program takes (FLASH_TILE: the causal diagonal tile is walked
+# in sub-blocks)
+@pytest.mark.parametrize("heads", [H, H // 2], ids=["level0", "level1"])
+def test_flash_attention_fwd_and_vjp_compile(one_chip, heads):
+    _assert_flash_compiles(one_chip, heads, causal=True, block=FLASH_TILE)
+
+
+# tiles computed in one piece: causal 128-tiles, bidirectional whole tiles
+@pytest.mark.parametrize("causal,block", [(True, 128), (False, FLASH_TILE)],
+                         ids=["causal_128", "bidir"])
+@pytest.mark.parametrize("heads", [H, H // 2], ids=["level0", "level1"])
+def test_flash_attention_whole_tiles_compile(one_chip, heads, causal, block):
+    _assert_flash_compiles(one_chip, heads, causal=causal, block=block)
 
 
 def test_paged_attention_decode_compiles(one_chip):
